@@ -48,6 +48,8 @@ def _selected(only: str, mod_name: str) -> bool:
 
 
 def main() -> int:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     only = sys.argv[1] if len(sys.argv) > 1 else None
     failed = []

@@ -584,10 +584,9 @@ class ShardedFacade(EngineFacade):
         return self.driver.all_members(self.state).astype(np.int64)
 
     def members(self, view=0, positive=True):
-        gids = np.asarray(self.state.gids)
-        lab = np.asarray(self.state.labels)[int(view)]
+        gids, labels, _ = self.driver.real_rows(self.state)
         want = 1 if positive else -1
-        return np.sort(gids[lab == want])
+        return np.sort(gids[labels[int(view)] == want])
 
     def predict(self, entity_id):
         labels, _ = self.point_labels_of(entity_id)
@@ -609,7 +608,7 @@ class ShardedFacade(EngineFacade):
         return np.zeros(self.num_views, bool)      # eager: nothing deferred
 
     def band_info(self, view=0):
-        eps = np.asarray(self.state.eps)           # (k, n), SHARED order
+        eps = self.driver.real_rows(self.state)[2]  # (k, n), SHARED order
         lw = self.driver.lw.astype(np.float32)
         hw = self.driver.hw.astype(np.float32)
         _, _, width = covering_windows(eps, lw, hw)
@@ -625,8 +624,8 @@ class ShardedFacade(EngineFacade):
 
     def top_margins(self, view=0, limit=10, descending=True):
         v = int(view)
-        eps = np.asarray(self.state.eps)[v]        # stored-model margins
-        gids = np.asarray(self.state.gids)
+        gids, _, eps = self.driver.real_rows(self.state)
+        eps = eps[v]                               # stored-model margins
         order = np.argsort(eps, kind="stable")
         return self._topk_from_sorted(
             eps[order], gids[order], self.driver.lw[v], self.driver.hw[v],

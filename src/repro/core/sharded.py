@@ -47,7 +47,7 @@ SKIING would usually have reorganized long before that.
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +58,10 @@ from repro.core.engine import (band_partition, classify, covering_windows,
                                probe_partition, waters_update)
 from repro.kernels.band_reclassify.ops import multiview_band_reclassify
 
-try:                                   # jax >= 0.6 exports it at top level
-    shard_map = jax.shard_map
-except AttributeError:                 # pinned 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map
+# f32 margins on every backend: XLA's TPU default for f32 dots is one bf16
+# pass, which would make the stored eps (and so the Lemma 3.1 band) only
+# bf16-accurate
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class ShardedHazyState(NamedTuple):
@@ -116,13 +116,14 @@ def make_naive_update_step(mesh: Mesh):
     model_ax = "model" if "model" in mesh.axis_names else None
 
     def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
-        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w)
+        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w,
+                       precision=HIGHEST)
         if model_ax:
             z = jax.lax.psum(z, model_ax)
         z = z - b
         return classify(z, xp=jnp)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=pr)
@@ -152,7 +153,8 @@ def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
         width = hi - lo
         start = jnp.clip(lo, 0, jnp.maximum(0, eps.shape[0] - cap))
         Fb = jax.lax.dynamic_slice(F, (start, 0), (cap, F.shape[1]))
-        z = jnp.einsum("nd,d->n", Fb.astype(jnp.float32), w)
+        z = jnp.einsum("nd,d->n", Fb.astype(jnp.float32), w,
+                       precision=HIGHEST)
         if model_ax:
             z = jax.lax.psum(z, model_ax)
         z = z - b
@@ -168,7 +170,7 @@ def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
             wmax = jax.lax.pmax(wmax, ax)
         return labels, wsum, wmax
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=(pr, P(), P()))
@@ -189,7 +191,8 @@ def make_reorganize_step(mesh: Mesh):
     model_ax = "model" if "model" in mesh.axis_names else None
 
     def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
-        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w)
+        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w,
+                       precision=HIGHEST)
         if model_ax:
             z = jax.lax.psum(z, model_ax)
         z = z - b
@@ -200,7 +203,7 @@ def make_reorganize_step(mesh: Mesh):
         labels_new = classify(eps_new, xp=jnp)
         return F_new, eps_new, labels_new, perm_new
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=(pf, pr, pr, pr))
@@ -223,7 +226,7 @@ def make_all_members_step(mesh: Mesh):
             c = jax.lax.psum(c, ax)
         return c
 
-    fn = shard_map(local, mesh=mesh, in_specs=(pr,), out_specs=P())
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pr,), out_specs=P())
     return lambda state: fn(state.labels)
 
 
@@ -312,18 +315,29 @@ class ShardedMultiViewState(NamedTuple):
     the perm (position -> global entity id); reorganization re-sorts rows,
     eps and labels together, entirely on device. F rows are kept whole
     (row-sharded, model-replicated) because the band kernel computes
-    sign(w_v·f − b_v) per row."""
-    F: jax.Array            # (n, d) f32 — scratch rows, shared order
-    gids: jax.Array         # (n,) i32 global entity id per scratch row
-    eps: jax.Array          # (k, n) f32 stored-model margins, shared order
-    labels: jax.Array       # (k, n) int8 aligned to the shared order
+    sign(w_v·f − b_v) per row.
+
+    Each shard holds a whole number of kernel tiles, so the table is padded
+    to n_pad rows. A padding row has gid `PAD_GID`, zero features, eps +inf
+    (never inside a band, sorted to the back of its shard) and label 0 (never
+    counted as a member of either side)."""
+    F: jax.Array            # (n_pad, d) f32 — scratch rows, shared order
+    gids: jax.Array         # (n_pad,) i32 global entity id per scratch row
+    eps: jax.Array          # (k, n_pad) f32 stored-model margins, shared order
+    labels: jax.Array       # (k, n_pad) int8 aligned to the shared order
     W_stored: jax.Array     # (k, d) f32 (replicated)
     b_stored: jax.Array     # (k,) f32
     lw: jax.Array           # (k,) f32
     hw: jax.Array           # (k,) f32
 
 
-def multiview_state_specs(n: int, d: int, k: int, mesh: Mesh,
+PAD_GID = -1                # gid of a padding row of the scratch table
+# bytes of one f32 (block_n, d) tile of F in VMEM; the kernel pipeline holds
+# two of them, which stays inside the smallest default scoped-VMEM limit
+VMEM_TILE_BYTES = 2 << 20
+
+
+def multiview_state_specs(n_pad: int, d: int, k: int, mesh: Mesh,
                           dtype=jnp.float32):
     row_axes = _row_axes(mesh)
     rows = P(row_axes)
@@ -333,10 +347,10 @@ def multiview_state_specs(n: int, d: int, k: int, mesh: Mesh,
         return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, spec))
 
     return ShardedMultiViewState(
-        F=sds((n, d), dtype, P(row_axes, None)),   # model-replicated rows
-        gids=sds((n,), jnp.int32, rows),
-        eps=sds((k, n), jnp.float32, krows),
-        labels=sds((k, n), jnp.int8, krows),
+        F=sds((n_pad, d), dtype, P(row_axes, None)),   # model-replicated rows
+        gids=sds((n_pad,), jnp.int32, rows),
+        eps=sds((k, n_pad), jnp.float32, krows),
+        labels=sds((k, n_pad), jnp.int8, krows),
         W_stored=sds((k, d), jnp.float32, P()),
         b_stored=sds((k,), jnp.float32, P()),
         lw=sds((k,), jnp.float32, P()),
@@ -349,39 +363,54 @@ def _mv_specs(mesh: Mesh):
     return (P(rows, None), P(rows), P(None, rows))
 
 
-def _mv_tiles(mesh: Mesh, n: int, cap_frac: float):
-    """Per-shard (n_local, block_n, cap) for the band kernel: block_n must
-    divide n_local, cap is tile-aligned in [block_n, n_local]."""
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def mv_tiles(mesh: Mesh, n: int, d: int, cap_frac: float):
+    """(n_pad, block_n, cap) for the band kernel.
+
+    block_n is a multiple of 128 (the TPU lane tile of the (1, block_n)
+    label blocks) sized so that one f32 (block_n, d) tile of F, lanes padded
+    to 128, fits `VMEM_TILE_BYTES`, and no larger than a shard needs. Each
+    row shard holds n_local rows: its share of the n real rows padded up to
+    a multiple of block_n, so the table holds n_pad = n_local * shards rows
+    (the padding sits at the end). cap is the per-shard kernel window,
+    tile-aligned in [block_n, n_local]."""
     rows = _row_axes(mesh)
     n_shards = int(np.prod([mesh.shape[a] for a in rows])) if rows else 1
-    n_local = n // n_shards
-    block_n = 512
-    while block_n > 8 and n_local % block_n:
-        block_n //= 2
-    if n_local % block_n:
-        block_n = n_local
-    cap = -(-max(block_n, int(n_local * cap_frac)) // block_n) * block_n
-    return n_local, block_n, min(cap, n_local)
+    per_shard = -(-n // n_shards)
+    block_n = VMEM_TILE_BYTES // (4 * _round_up(d, 128)) // 128 * 128
+    block_n = min(max(128, block_n), _round_up(per_shard, 128))
+    n_local = _round_up(per_shard, block_n)
+    cap = _round_up(max(block_n, int(n_local * cap_frac)), block_n)
+    return n_local * n_shards, block_n, min(cap, n_local)
 
 
-def make_multiview_update_step(mesh: Mesh, n: int, k: int,
-                               cap_frac: float = 1 / 64,
-                               interpret: Optional[bool] = None):
+def kernel_interpret(mesh: Mesh) -> bool:
+    """The band kernel is interpreted only on a CPU mesh: a TPU mesh always
+    gets the compiled kernel, and any other platform is an error."""
+    platform = mesh.devices.flat[0].platform
+    if platform not in ("cpu", "tpu"):
+        raise ValueError(f"the band kernel runs on TPU (or interpreted on "
+                         f"CPU); this mesh is on {platform!r}")
+    return platform == "cpu"
+
+
+def make_multiview_update_step(mesh: Mesh, block_n: int, cap: int):
     """Banded incremental step for all k views in ONE Pallas launch.
 
     Per shard: `engine.covering_windows` locates each view's covering
     window of the Lemma 3.1 band in the shared order (pure device compute,
     no per-view dynamic slices), then `multiview_band_reclassify` streams
     only the union of the k windows HBM->VMEM and relabels them under the
-    stacked models. Returns (state', true band widths (k,), overflow flag
+    stacked models. Returns (labels', true band widths (k,), overflow flag
     () i32 — nonzero when some view's window exceeded the kernel capacity
     on some shard, i.e. rows past the capacity kept stale labels and the
     driver must reorganize)."""
     pf, pr, pkr = _mv_specs(mesh)
     rows = _row_axes(mesh)
-    n_local, block_n, cap = _mv_tiles(mesh, n, cap_frac)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = kernel_interpret(mesh)
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b):
         start, end, width = covering_windows(eps, lw, hw, xp=jnp)
@@ -395,38 +424,46 @@ def make_multiview_update_step(mesh: Mesh, n: int, k: int,
             ov = jax.lax.pmax(ov, ax)
         return labels, wsum, ov
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P()),
         out_specs=(pkr, P(), P()),
-        check_rep=False)     # no replication rule for pallas_call (jax#21400)
+        check_vma=False)     # pallas_call outputs carry no varying-axes type
 
     def step(state: ShardedMultiViewState, W, b):
-        labels, wsum, ov = fn(*state, W, b)
-        return state._replace(labels=labels), wsum, ov
+        # only the new labels leave the step: returning the whole state
+        # would make the jitted program copy F (the table) on every round
+        return fn(*state, W, b)
 
-    return step, cap
+    return step
 
 
 def make_multiview_reorganize_step(mesh: Mesh):
     """Re-sort the SHARED clustering order from one `F @ W.T` product: the
     new order sorts shard-local rows by min_v |eps_v| so that every view's
-    band clusters near the front of the shard. Rows, gids, eps and labels
-    move together; no collectives at all (shard-local clustering, and F
-    rows are whole so there is no model-axis psum either)."""
+    band clusters near the front of the shard, and padding rows (eps +inf)
+    to its back. Rows, gids, eps and labels move together; no collectives
+    at all (shard-local clustering, and F rows are whole so there is no
+    model-axis psum either)."""
     pf, pr, pkr = _mv_specs(mesh)
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b):
-        Z = jnp.einsum("nd,kd->kn", F.astype(jnp.float32), W) - b[:, None]
+        Z = jnp.einsum("nd,kd->kn", F.astype(jnp.float32), W,
+                       precision=HIGHEST) - b[:, None]
+        Z = jnp.where(gids[None, :] == PAD_GID, jnp.inf, Z)
         key = jnp.min(jnp.abs(Z), axis=0)          # nearest-boundary distance
-        order = jnp.argsort(key).astype(jnp.int32)
+        # the same stable argsort over a (1, n) row: the TPU compiler takes
+        # half as long over it as over the 1-D array (~45 s vs ~95 s at
+        # 721,408 rows)
+        order = jnp.argsort(key[None, :], axis=1)[0].astype(jnp.int32)
         F_new = jnp.take(F, order, axis=0)
         gids_new = jnp.take(gids, order)
         eps_new = jnp.take(Z, order, axis=1)
-        labels_new = classify(eps_new, xp=jnp)
+        labels_new = jnp.where(gids_new[None, :] == PAD_GID, jnp.int8(0),
+                               classify(eps_new, xp=jnp))
         return F_new, gids_new, eps_new, labels_new
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P()),
         out_specs=(pf, pr, pkr, pkr))
@@ -457,7 +494,7 @@ def make_multiview_hybrid_probe_step(mesh: Mesh):
         lab = probe_partition(e, lw, hw, xp=jnp)
         return lab, lab != 0, e
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P()))
@@ -478,13 +515,14 @@ def make_multiview_entity_margin_step(mesh: Mesh):
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b, eid):
         hit = (gids == eid).astype(jnp.float32)           # (n_local,)
-        f = jnp.einsum("n,nd->d", hit, F.astype(jnp.float32))
-        z = jnp.einsum("kd,d->k", W, f)
+        f = jnp.einsum("n,nd->d", hit, F.astype(jnp.float32),
+                       precision=HIGHEST)
+        z = jnp.einsum("kd,d->k", W, f, precision=HIGHEST)
         for ax in rows:            # other row shards contribute exact zeros
             z = jax.lax.psum(z, ax)
         return z - b
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P(), P()),
         out_specs=P())
@@ -505,7 +543,7 @@ def make_multiview_all_members_step(mesh: Mesh):
             c = jax.lax.psum(c, ax)
         return c
 
-    fn = shard_map(local, mesh=mesh, in_specs=(pkr,), out_specs=P())
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(pkr,), out_specs=P())
     return lambda state: fn(state.labels)
 
 
@@ -517,7 +555,8 @@ class ShardedMultiViewHazy:
     `apply_models` reclassifies the union band through the
     `multiview_band_reclassify` kernel against the device-resident shared
     clustering order, and falls back to reorganization whenever the kernel
-    reports a covering-window overflow (stale labels would ship otherwise)."""
+    reports a covering-window overflow (stale labels would ship otherwise).
+    `n` counts real entity rows; the device table holds `n_pad` rows."""
     mesh: Mesh
     n: int
     d: int
@@ -526,12 +565,12 @@ class ShardedMultiViewHazy:
     p: float = 2.0
     alpha: float = 1.0
     cap_frac: float = 1 / 64
-    interpret: Optional[bool] = None
 
     def __post_init__(self):
-        up, self.cap = make_multiview_update_step(
-            self.mesh, self.n, self.k, self.cap_frac, interpret=self.interpret)
-        self._update = jax.jit(up)
+        self.n_pad, self.block_n, self.cap = mv_tiles(
+            self.mesh, self.n, self.d, self.cap_frac)
+        self._update = jax.jit(
+            make_multiview_update_step(self.mesh, self.block_n, self.cap))
         self._reorg = jax.jit(make_multiview_reorganize_step(self.mesh))
         self._count = jax.jit(make_multiview_all_members_step(self.mesh))
         self._probe = jax.jit(make_multiview_hybrid_probe_step(self.mesh))
@@ -540,17 +579,23 @@ class ShardedMultiViewHazy:
         self.skiing = Skiing(S=1.0, alpha=self.alpha)
         self.lw = np.zeros(self.k, np.float64)
         self.hw = np.zeros(self.k, np.float64)
+        self.kernel_rounds = 0    # update-step launches of the band kernel
         self.overflows = 0        # kernel-capacity overflow -> forced reorg
 
     def init_state(self, F: np.ndarray) -> ShardedMultiViewState:
-        specs = multiview_state_specs(self.n, self.d, self.k, self.mesh)
+        k, n, n_pad = self.k, self.n, self.n_pad
+        specs = multiview_state_specs(n_pad, self.d, k, self.mesh)
         put = lambda x, s: jax.device_put(x, s.sharding)
-        k, n = self.k, self.n
+        F = np.asarray(F, np.float32)
+        if n_pad > n:
+            F = np.concatenate([F, np.zeros((n_pad - n, self.d), np.float32)])
+        gids = np.full(n_pad, PAD_GID, np.int32)
+        gids[:n] = np.arange(n, dtype=np.int32)
         state = ShardedMultiViewState(
-            F=put(F.astype(np.float32), specs.F),
-            gids=put(np.arange(n, dtype=np.int32), specs.gids),
-            eps=put(np.zeros((k, n), np.float32), specs.eps),
-            labels=put(np.ones((k, n), np.int8), specs.labels),
+            F=put(F, specs.F),
+            gids=put(gids, specs.gids),
+            eps=put(np.zeros((k, n_pad), np.float32), specs.eps),
+            labels=put(np.zeros((k, n_pad), np.int8), specs.labels),
             W_stored=put(np.zeros((k, self.d), np.float32), specs.W_stored),
             b_stored=put(np.zeros(k, np.float32), specs.b_stored),
             lw=put(np.zeros(k, np.float32), specs.lw),
@@ -576,9 +621,11 @@ class ShardedMultiViewHazy:
             self.lw, self.hw, np.asarray(W), np.asarray(b, np.float64),
             np.asarray(state.W_stored),
             np.asarray(state.b_stored, np.float64), self.M, self.p)
-        state, wsum, overflow = self._update(
-            state._replace(lw=jnp.asarray(self.lw, jnp.float32),
-                           hw=jnp.asarray(self.hw, jnp.float32)), W, b32)
+        state = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
+                               hw=jnp.asarray(self.hw, jnp.float32))
+        labels, wsum, overflow = self._update(state, W, b32)
+        state = state._replace(labels=labels)
+        self.kernel_rounds += 1
         if int(overflow):
             # some view's covering window outgrew the kernel capacity on
             # some shard: its labels past the capacity are stale — rebuild
@@ -591,6 +638,14 @@ class ShardedMultiViewHazy:
 
     def all_members(self, state) -> np.ndarray:
         return np.asarray(self._count(state))
+
+    def real_rows(self, state: ShardedMultiViewState):
+        """(gids, labels, eps) of the real rows on the host, in the shared
+        clustering order: padding rows dropped."""
+        gids = np.asarray(state.gids)
+        real = gids != PAD_GID
+        return (gids[real], np.asarray(state.labels)[:, real],
+                np.asarray(state.eps)[:, real])
 
     def hybrid_labels_of(self, state: ShardedMultiViewState, W, b,
                          entity_id: int):

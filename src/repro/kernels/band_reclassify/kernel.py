@@ -32,18 +32,21 @@ def _band_kernel(scalars_ref,           # (2,) i32: [start_block, width]
 
 
 def _mv_band_kernel(scalars_ref,        # (2, k) i32: [start_block_v; width_v]
-                    w_ref, b_ref, f_ref, lab_in_ref, lab_out_ref):
+                    b_ref,              # (1, k) f32 in SMEM
+                    w_ref,              # (k, d) — every view's model, one block
+                    f_ref, lab_in_ref, lab_out_ref):
     v = pl.program_id(0)
     i = pl.program_id(1)
     width = scalars_ref[1, v]
     bn = f_ref.shape[0]
     f = f_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    eps = jnp.sum(f * w, axis=1)[None, :] - b_ref[0, 0]
-    new = jnp.where(eps >= 0, 1, -1).astype(jnp.int8)
+    w = w_ref[pl.ds(v, 1), :].astype(jnp.float32)
+    eps = jnp.sum(f * w, axis=1)[None, :] - b_ref[0, v]
+    # select in int32: Mosaic cannot relayout an int8-typed (1, bn) mask
+    new = jnp.where(eps >= 0, 1, -1)
     offs = i * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
-    in_band = offs < width
-    lab_out_ref[...] = jnp.where(in_band, new, lab_in_ref[...])
+    old = lab_in_ref[...].astype(jnp.int32)
+    lab_out_ref[...] = jnp.where(offs < width, new, old).astype(jnp.int8)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "block_n", "interpret"))
@@ -57,6 +60,11 @@ def multiview_band_reclassify(F, labels, W, b, start_blocks, widths, *,
     (k, n) int8, row v aligned to the SAME row order as F, updated in
     place; W: (k, d); b: (k,); start_blocks/widths: (k,) i32 — per-view
     windows in units of block_n rows.
+
+    Block layouts follow the TPU tiling rule (the last two block dims are
+    multiples of (8, 128) or the whole array dims): W is one whole (k, d)
+    block read at row v, b sits in SMEM, and labels travel as (k, 1, n)
+    with (1, block_n) tiles, so block_n must be a multiple of 128 on TPU.
 
     Grid is (k, cap // block_n): program (v, i) streams the i-th tile of
     view v's window and relabels it under view v's model. Each view's
@@ -73,24 +81,27 @@ def multiview_band_reclassify(F, labels, W, b, start_blocks, widths, *,
     scalars = jnp.stack([start_blocks.astype(jnp.int32),
                          widths.astype(jnp.int32)])
 
+    lab_spec = pl.BlockSpec((pl.squeezed, 1, block_n),
+                            lambda v, i, s: (v, 0, s[0, v] + i))
     out = pl.pallas_call(
         _mv_band_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, d), lambda v, i, s: (v, 0)),
-                pl.BlockSpec((1, 1), lambda v, i, s: (v, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec((k, d), lambda v, i, s: (0, 0)),
                 pl.BlockSpec((block_n, d), lambda v, i, s: (s[0, v] + i, 0)),
-                pl.BlockSpec((1, block_n), lambda v, i, s: (v, s[0, v] + i)),
+                lab_spec,
             ],
-            out_specs=pl.BlockSpec((1, block_n), lambda v, i, s: (v, s[0, v] + i)),
+            out_specs=lab_spec,
         ),
-        out_shape=jax.ShapeDtypeStruct((k, n), jnp.int8),
+        out_shape=jax.ShapeDtypeStruct((k, 1, n), jnp.int8),
         input_output_aliases={4: 0},
         interpret=interpret,
-    )(scalars, W, b.reshape(-1, 1).astype(jnp.float32), F, labels)
-    return out
+    )(scalars, b.reshape(1, -1).astype(jnp.float32), W, F,
+      labels.reshape(k, 1, n))
+    return out.reshape(k, n)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "block_n", "interpret"))

@@ -3,15 +3,13 @@ built inside the function, per the dry-run contract."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` across JAX versions: explicit `axis_types` only
-    exists from jax 0.5; on older pins every axis is Auto by default."""
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return jax.make_mesh(shape, axes, axis_types=(at.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (sharding left to the compiler
+    and to the shard_map specs)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,10 +28,7 @@ def make_elastic_mesh(num_devices: int, *, model_parallel: int = 16):
     import numpy as np
     arr = np.array(devices).reshape(data, model_parallel)
     from jax.sharding import Mesh
-    at = getattr(jax.sharding, "AxisType", None)
-    if at is not None:
-        return Mesh(arr, ("data", "model"), axis_types=(at.Auto,) * 2)
-    return Mesh(arr, ("data", "model"))
+    return Mesh(arr, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")):

@@ -122,6 +122,8 @@ def main():
                     help="sql mode: access log — one structured line per "
                          "served statement")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "decode":
         serve_decode(args.arch, args.steps, args.batch, args.cache_len)
     elif args.mode == "sql":

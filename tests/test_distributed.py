@@ -76,8 +76,7 @@ def test_compressed_allreduce_accuracy():
             out, err2 = allred({"g": g}, err)
             return out["g"], err2["g"]
 
-        from repro.core.sharded import shard_map
-        fn = jax.jit(shard_map(f, mesh=mesh,
+        fn = jax.jit(jax.shard_map(f, mesh=mesh,
                                    in_specs=(P("pod"), P("pod")),
                                    out_specs=(P("pod"), P("pod"))))
         # accumulate over rounds: error feedback must keep the running mean
@@ -130,23 +129,16 @@ def test_sharded_hazy_multidevice_consistency():
     assert "OK" in out
 
 
-def test_sharded_multiview_multidevice_consistency():
-    """k one-vs-all views over ONE shared scratch table on a (4, 2) mesh,
-    maintained through the `multiview_band_reclassify` kernel against the
-    device-resident shared clustering order: after the same cora_like SGD
-    stream, the sharded labels and counts must equal the host
-    `MultiViewEngine`'s (both are exact w.r.t. the current model, so any
-    disagreement is a maintenance bug on one side)."""
-    out = _run_subprocess("""
+_MULTIVIEW_VS_HOST = """
         import numpy as np, jax, jax.numpy as jnp
-        from repro.core.sharded import ShardedMultiViewHazy
+        from repro.core.sharded import PAD_GID, ShardedMultiViewHazy
         from repro.core.multiview import MultiViewEngine
         from repro.core.waters import holder_M
         from repro.data import cora_like, multiclass_example_stream
         from repro.launch.mesh import make_mesh
-        mesh = make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh(MESH_SHAPE, ("data", "model"))
         c = cora_like(scale=0.8)
-        n, k = 2048, c.num_classes            # 4 row shards of 512
+        n, k = N_ROWS, c.num_classes
         F = np.ascontiguousarray(c.features[:n]); d = F.shape[1]
         host = MultiViewEngine(F, k, p=2.0, q=2.0, cost_mode="modeled")
         sh = ShardedMultiViewHazy(mesh=mesh, n=n, d=d, k=k,
@@ -155,7 +147,7 @@ def test_sharded_multiview_multidevice_consistency():
         W = np.zeros((k, d), np.float32); b = np.zeros(k, np.float64)
         lr, l2 = 0.1, 1e-4
         stream = multiclass_example_stream(c, seed=11)
-        for i, cls in (next(stream) for _ in range(300)):
+        for i, cls in (next(stream) for _ in range(N_EXAMPLES)):
             if i >= n:
                 continue
             f = F[i]
@@ -169,8 +161,11 @@ def test_sharded_multiview_multidevice_consistency():
             state = sh.apply_models(state, W, b)
         # labels: sharded rows live in the shared clustering order (gids);
         # scatter the host's per-view eps order back to entity order first
-        gids = np.asarray(state.gids)
-        labels = np.asarray(state.labels)
+        gids, labels, _ = sh.real_rows(state)
+        assert np.array_equal(np.sort(gids), np.arange(n))   # no padding
+        pad = np.asarray(state.gids) == PAD_GID
+        assert pad.sum() == sh.n_pad - n
+        assert not np.asarray(state.labels)[:, pad].any()
         host_full = np.empty((k, n), np.int8)
         for v in range(k):
             host_full[v, host.perm[v]] = host.labels_sorted[v]
@@ -191,7 +186,35 @@ def test_sharded_multiview_multidevice_consistency():
         assert resolved_total > 0      # the waters tier did real work
         print("OK reorgs=", sh.skiing.reorgs, "overflows=", sh.overflows,
               "counts=", counts, "water_resolved=", resolved_total)
-    """)
+"""
+
+
+def _sharded_multiview_vs_host(mesh_shape, n_rows, n_examples) -> str:
+    """Run `_MULTIVIEW_VS_HOST` on a mesh of fake CPU devices: the stream's
+    first `n_examples` draws train the views (those past `n_rows` are
+    skipped)."""
+    return _run_subprocess(_MULTIVIEW_VS_HOST
+                           .replace("MESH_SHAPE", repr(mesh_shape))
+                           .replace("N_ROWS", str(n_rows))
+                           .replace("N_EXAMPLES", str(n_examples)))
+
+
+def test_sharded_multiview_multidevice_consistency():
+    """k one-vs-all views over ONE shared scratch table on a (4, 2) mesh,
+    maintained through the `multiview_band_reclassify` kernel against the
+    device-resident shared clustering order: after the same cora_like SGD
+    stream, the sharded labels and counts must equal the host
+    `MultiViewEngine`'s (both are exact w.r.t. the current model, so any
+    disagreement is a maintenance bug on one side)."""
+    out = _sharded_multiview_vs_host((4, 2), 2048, 300)  # 4 shards of 512
+    assert "OK" in out
+
+
+def test_sharded_multiview_multidevice_unaligned_rows():
+    """The same check with 1000 rows on a (4, 1) mesh: four shards of one
+    256-row kernel tile hold 1024 rows, and the 24 padding rows (all on the
+    last shard) must never show up in labels, counts or point reads."""
+    out = _sharded_multiview_vs_host((4, 1), 1000, 600)
     assert "OK" in out
 
 

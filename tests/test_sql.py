@@ -382,6 +382,68 @@ def test_sql_equals_direct_sharded():
     assert facade.driver.skiing.reorgs == driver.skiing.reorgs
 
 
+@pytest.mark.parametrize("n", [1000, 1157])
+def test_sql_sharded_unaligned_table_matches_multiview(n):
+    """engine=sharded on a table whose row count is no multiple of the
+    kernel's 128-row tile, so the device table carries padding rows. Through
+    SQL, its labels, counts, members and point reads must equal a host
+    `MultiViewEngine` view on the same table and stream, and no padding row
+    may ever be counted or returned."""
+    pytest.importorskip("jax")
+    from repro.core.sharded import PAD_GID
+
+    k, d = 4, 16
+    c = multiclass_corpus("pad", n, d, k, seed=3)
+    catalog = Catalog()
+    catalog.register_table("t", c.features, truth=c.classes, num_classes=k)
+    opts = {"k": k, "p": 2, "q": 2, "lr": 0.5}
+    catalog.create_view("dev", "t", "svm", {**opts, "engine": "sharded"})
+    catalog.create_view("host", "t", "svm", {**opts, "engine": "multiview"})
+    driver = catalog.view("dev").facade.driver
+    assert driver.n_pad > n and driver.n_pad % driver.block_n == 0
+    ex = Executor(catalog, group_commit=GROUP)
+
+    rng = np.random.default_rng(4)
+    for _ in range(8):
+        ids = rng.integers(0, n, GROUP)
+        ex.execute_one("INSERT INTO t (id, class) VALUES " + ", ".join(
+            f"({int(i)}, {int(c.classes[i])})" for i in ids))
+    ex.execute_one("COMMIT")
+    assert driver.kernel_rounds > driver.overflows    # the kernel relabeled
+
+    def q(sql):
+        return ex.execute_one(sql).rows
+
+    for v in range(k):
+        counts = [q(f"SELECT count(*) FROM {view} WHERE class = {v}")[0][0]
+                  for view in ("dev", "host")]
+        assert counts[0] == counts[1], (v, counts)
+        sides = {}
+        for lab in (1, -1):
+            got = [sorted(r[0] for r in q(f"SELECT id FROM {view} WHERE "
+                                          f"class = {v} AND label = {lab}"))
+                   for view in ("dev", "host")]
+            assert got[0] == got[1], (v, lab)
+            sides[lab] = got[0]
+        # the two sides list every real entity once and nothing else
+        assert sorted(sides[1] + sides[-1]) == list(range(n))
+        assert len(sides[1]) == counts[0]
+    assert 0 < sum(len(q(f"SELECT id FROM dev WHERE class = {v} AND "
+                         f"label = 1")) for v in range(k)) < n * k
+    for i in [0, n // 2, n - 1, *rng.integers(0, n, 8)]:
+        got = [q(f"SELECT id, view, label FROM {view} WHERE id = {int(i)}")
+               for view in ("dev", "host")]
+        assert got[0] == got[1], i
+
+    gids, labels, eps = driver.real_rows(catalog.view("dev").facade.state)
+    assert sorted(gids) == list(range(n)) and PAD_GID not in gids
+    assert set(np.unique(labels)) <= {-1, 1}
+    state = catalog.view("dev").facade.state
+    pad = np.asarray(state.gids) == PAD_GID
+    assert pad.sum() == driver.n_pad - n
+    assert not np.asarray(state.labels)[:, pad].any()       # label 0
+
+
 # ---------------------------------------------------------------------------
 # Hybrid point SELECTs: tier counters (acceptance criterion)
 # ---------------------------------------------------------------------------

@@ -1,0 +1,106 @@
+"""Compile-only checks of the device path for a described TPU v5e chip.
+
+The TPU compiler is installed even where no chip is attached: it refuses
+what the chip would refuse (block shapes off the (8, 128) tiling, more VMEM
+than a kernel may use, a program larger than device memory). These tests
+compile the band kernel and the sharded engine's jitted steps at the
+paper's table sizes for one described v5e device. Nothing runs.
+
+The topology is described inside a module fixture, never while a module is
+imported: only one process at a time may load the TPU library, and the
+test workers import every test file.
+"""
+import os
+
+import numpy as np
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+from jax.sharding import (AxisType, Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P)
+
+from repro.core.sharded import (ShardedMultiViewHazy, kernel_interpret,  # noqa: E402
+                                multiview_state_specs)
+from repro.kernels.band_reclassify.ops import multiview_band_reclassify  # noqa: E402
+
+V5E_HBM_BYTES = 16e9
+K = 16
+CAP_FRAC = 0.5                      # make_sharded_facade's default
+# (n real rows, d): Citeseer at the hashed width chip_smoke.py serves, and
+# Forest's 54 dense features
+SHAPES = {"citeseer": (721_000, 1024), "forest": (582_000, 54)}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip_mesh(topo):
+    return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+
+
+def _driver(mesh, name):
+    n, d = SHAPES[name]
+    return ShardedMultiViewHazy(mesh=mesh, n=n, d=d, k=K, M=1.0,
+                                cap_frac=CAP_FRAC)
+
+
+def _device_bytes(compiled) -> int:
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_band_kernel_compiles_for_v5e(one_chip_mesh, name):
+    """The band kernel alone, at the padded table size and tiling the
+    engine derives for one chip."""
+    dr = _driver(one_chip_mesh, name)
+    assert dr.n_pad >= dr.n and dr.n_pad % dr.block_n == 0
+    assert dr.block_n % 128 == 0 and dr.cap % dr.block_n == 0
+    d = dr.d
+    rep = NamedSharding(one_chip_mesh, P())
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    fn = jax.jit(lambda F, L, W, b, s, e: multiview_band_reclassify(
+        F, L, W, b, s, e, cap=dr.cap, block_n=dr.block_n,
+        with_overflow=True))
+    compiled = fn.lower(sds((dr.n_pad, d), jnp.float32),
+                        sds((K, dr.n_pad), jnp.int8),
+                        sds((K, d), jnp.float32), sds((K,), jnp.float32),
+                        sds((K,), jnp.int32), sds((K,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("step", ["update", "reorganize"])
+def test_multiview_steps_compile_for_v5e(one_chip_mesh, step):
+    """The engine's jitted maintenance steps at Citeseer size on a
+    one-device mesh: the update step launches the compiled kernel (never
+    the interpreter on a TPU mesh) and returns only labels, not a copy of
+    the table; both fit one chip's memory."""
+    assert kernel_interpret(one_chip_mesh) is False
+    dr = _driver(one_chip_mesh, "citeseer")
+    state = multiview_state_specs(dr.n_pad, dr.d, K, one_chip_mesh)
+    rep = NamedSharding(one_chip_mesh, P())
+    W = jax.ShapeDtypeStruct((K, dr.d), jnp.float32, sharding=rep)
+    b = jax.ShapeDtypeStruct((K,), jnp.float32, sharding=rep)
+    fn = dr._update if step == "update" else dr._reorg
+    compiled = fn.lower(state, W, b).compile()
+    assert _device_bytes(compiled) < V5E_HBM_BYTES
+    if step == "update":
+        assert "tpu_custom_call" in compiled.as_text()
+        table_bytes = dr.n_pad * dr.d * 4
+        assert compiled.memory_analysis().output_size_in_bytes < table_bytes
