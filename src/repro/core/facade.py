@@ -39,6 +39,7 @@ from repro.core.engine import (PROBE_TIERS, band_partition, covering_windows,
                                probe_partition, waters_update)
 from repro.core.multiclass import MulticlassView, sgd_all_views
 from repro.core.view import ClassificationView
+from repro.obs import trace
 
 # "pool" = probe miss answered by a resident page of the memory-budgeted
 # storage tier (repro.storage.BufferPool); "disk" = a COLD page read. For
@@ -550,9 +551,13 @@ class ShardedFacade(EngineFacade):
         self._disk = 0
 
     def insert_examples(self, ids, labels):
-        for i, c in zip(ids, labels):
-            self.W, self.b = sgd_all_views(self.W, self.b, self.F[int(i)],
-                                           int(c), lr=self.lr, l2=self.l2)
+        # `round.sgd`: the stacked SGD on the host, before the driver's
+        # round.* spans (`ShardedMultiViewHazy.apply_models`)
+        with trace.span("round.sgd", metrics=self.driver.metrics):
+            for i, c in zip(ids, labels):
+                self.W, self.b = sgd_all_views(
+                    self.W, self.b, self.F[int(i)], int(c), lr=self.lr,
+                    l2=self.l2)
         self.state = self.driver.apply_models(self.state, self.W, self.b)
 
     def force_round(self):
@@ -637,8 +642,9 @@ class ShardedFacade(EngineFacade):
 def make_sharded_facade(features: np.ndarray, k: int, *, p: float = 2.0,
                         q: float = 2.0, lr: float = 0.1, l2: float = 1e-4,
                         alpha: float = 1.0, cap_frac: float = 0.5,
-                        mesh=None) -> ShardedFacade:
-    """Build a `ShardedFacade` on `mesh` (default: single-host (1, 1))."""
+                        mesh=None, metrics=None) -> ShardedFacade:
+    """Build a `ShardedFacade` on `mesh` (default: single-host (1, 1)); its
+    spans and compile counts go to the registry `metrics`."""
     from repro.core.sharded import ShardedMultiViewHazy
     from repro.core.waters import holder_M
     if mesh is None:
@@ -647,5 +653,6 @@ def make_sharded_facade(features: np.ndarray, k: int, *, p: float = 2.0,
     F = np.ascontiguousarray(features, np.float32)
     driver = ShardedMultiViewHazy(
         mesh=mesh, n=F.shape[0], d=F.shape[1], k=int(k),
-        M=holder_M(F, q), p=p, alpha=alpha, cap_frac=cap_frac)
+        M=holder_M(F, q), p=p, alpha=alpha, cap_frac=cap_frac,
+        metrics=metrics)
     return ShardedFacade(driver, F, lr=lr, l2=l2)

@@ -43,11 +43,19 @@ aligned). The kernel reports per-view window overflow
 (`with_overflow=True`) and the host driver triggers reorganization instead
 of shipping the stale labels a truncated window would leave behind —
 SKIING would usually have reorganized long before that.
+
+Observation: every jitted program is named for what it does (`PROGRAMS`),
+so the profiler's "XLA Modules" line and JAX's compile events say which
+one ran or recompiled. A driver counts the compiles of those programs in
+its metrics registry and mirrors every `repro.obs` span into the
+profiler's host trace (`observe`). The multi-view driver opens `round.*`
+spans inside a maintenance round and `read.*` spans inside a point read.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import NamedTuple
+import weakref
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,6 +65,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.engine import (band_partition, classify, covering_windows,
                                probe_partition, waters_update)
 from repro.kernels.band_reclassify.ops import multiview_band_reclassify
+from repro.obs import trace
 
 # f32 margins on every backend: XLA's TPU default for f32 dots is one bf16
 # pass, which would make the stored eps (and so the Lemma 3.1 band) only
@@ -108,6 +117,54 @@ def _specs(mesh: Mesh):
 
 
 # ---------------------------------------------------------------------------
+# Observation: named programs, their compiles, and the span mirror
+# ---------------------------------------------------------------------------
+
+# the drivers' jitted programs, by the name each step function carries: the
+# trace's "XLA Modules" line shows `jit_<name>`, compile events `jit(<name>)`
+PROGRAMS = ("band_update", "reorganize", "probe", "margin", "all_members",
+            "naive_update")
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_watching: "weakref.WeakSet" = weakref.WeakSet()   # registries counting
+_listening = False                                  # compiles
+
+
+def _count_compile(event: str, duration: float, **kw: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    name = str(kw.get("fun_name", ""))
+    if name.startswith("jit(") and name.endswith(")"):
+        name = name[4:-1]
+    if name not in PROGRAMS:
+        return
+    for reg in list(_watching):
+        reg.counter(f"compiles.{name}").inc()
+        reg.histogram(f"compiles.{name}.seconds").observe(duration)
+
+
+def _profiler_annotation(name: str):
+    """The span's host annotation while a profile is being collected, else
+    None (a span then costs one check more)."""
+    ann = jax.profiler.TraceAnnotation
+    return ann(name) if ann.is_enabled() else None
+
+
+def observe(metrics: Optional[Any] = None) -> None:
+    """Mirror every `repro.obs` span into the profiler's host trace, and
+    count this process's compiles of the named `PROGRAMS` in `metrics`:
+    counters `compiles.<program>` and histograms
+    `compiles.<program>.seconds` (a persistent-cache hit counts too)."""
+    global _listening
+    trace.set_mirror(_profiler_annotation)
+    if metrics is None:
+        return
+    if not _listening:
+        jax.monitoring.register_event_duration_secs_listener(_count_compile)
+        _listening = True
+    _watching.add(metrics)
+
+
+# ---------------------------------------------------------------------------
 # Steps (built per mesh; call under `with mesh:` or pass to jit/lower)
 # ---------------------------------------------------------------------------
 
@@ -128,11 +185,11 @@ def make_naive_update_step(mesh: Mesh):
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=pr)
 
-    def step(state: ShardedHazyState, w, b):
+    def naive_update(state: ShardedHazyState, w, b):
         labels = fn(*state, w, b)
         return state._replace(labels=labels)
 
-    return step
+    return naive_update
 
 
 def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
@@ -175,11 +232,11 @@ def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=(pr, P(), P()))
 
-    def step(state: ShardedHazyState, w, b):
+    def band_update(state: ShardedHazyState, w, b):
         labels, wsum, wmax = fn(*state, w, b)
         return state._replace(labels=labels), wsum, wmax
 
-    return step, cap
+    return band_update, cap
 
 
 def make_reorganize_step(mesh: Mesh):
@@ -208,12 +265,12 @@ def make_reorganize_step(mesh: Mesh):
         in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
         out_specs=(pf, pr, pr, pr))
 
-    def step(state: ShardedHazyState, w, b):
+    def reorganize(state: ShardedHazyState, w, b):
         F, eps, labels, perm = fn(*state, w, b)
         return ShardedHazyState(F, eps, labels, perm, w, b,
                                 jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
 
-    return step
+    return reorganize
 
 
 def make_all_members_step(mesh: Mesh):
@@ -227,7 +284,11 @@ def make_all_members_step(mesh: Mesh):
         return c
 
     fn = jax.shard_map(local, mesh=mesh, in_specs=(pr,), out_specs=P())
-    return lambda state: fn(state.labels)
+
+    def all_members(state):
+        return fn(state.labels)
+
+    return all_members
 
 
 # ---------------------------------------------------------------------------
@@ -430,12 +491,12 @@ def make_multiview_update_step(mesh: Mesh, block_n: int, cap: int):
         out_specs=(pkr, P(), P()),
         check_vma=False)     # pallas_call outputs carry no varying-axes type
 
-    def step(state: ShardedMultiViewState, W, b):
+    def band_update(state: ShardedMultiViewState, W, b):
         # only the new labels leave the step: returning the whole state
         # would make the jitted program copy F (the table) on every round
         return fn(*state, W, b)
 
-    return step
+    return band_update
 
 
 def make_multiview_reorganize_step(mesh: Mesh):
@@ -468,12 +529,12 @@ def make_multiview_reorganize_step(mesh: Mesh):
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P()),
         out_specs=(pf, pr, pkr, pkr))
 
-    def step(state: ShardedMultiViewState, W, b):
+    def reorganize(state: ShardedMultiViewState, W, b):
         F, gids, eps, labels = fn(*state, W, b)
         zeros = jnp.zeros(b.shape, jnp.float32)
         return ShardedMultiViewState(F, gids, eps, labels, W, b, zeros, zeros)
 
-    return step
+    return reorganize
 
 
 def make_multiview_hybrid_probe_step(mesh: Mesh):
@@ -499,10 +560,10 @@ def make_multiview_hybrid_probe_step(mesh: Mesh):
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P()),
         out_specs=(P(), P(), P()))
 
-    def step(state: ShardedMultiViewState, entity_id):
+    def probe(state: ShardedMultiViewState, entity_id):
         return fn(*state, entity_id)
 
-    return step
+    return probe
 
 
 def make_multiview_entity_margin_step(mesh: Mesh):
@@ -527,10 +588,10 @@ def make_multiview_entity_margin_step(mesh: Mesh):
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P(), P()),
         out_specs=P())
 
-    def step(state: ShardedMultiViewState, W, b, entity_id):
+    def margin(state: ShardedMultiViewState, W, b, entity_id):
         return fn(*state, W, b, entity_id)
 
-    return step
+    return margin
 
 
 def make_multiview_all_members_step(mesh: Mesh):
@@ -544,7 +605,11 @@ def make_multiview_all_members_step(mesh: Mesh):
         return c
 
     fn = jax.shard_map(local, mesh=mesh, in_specs=(pkr,), out_specs=P())
-    return lambda state: fn(state.labels)
+
+    def all_members(state):
+        return fn(state.labels)
+
+    return all_members
 
 
 @dataclasses.dataclass
@@ -565,8 +630,10 @@ class ShardedMultiViewHazy:
     p: float = 2.0
     alpha: float = 1.0
     cap_frac: float = 1 / 64
+    metrics: Optional[Any] = None     # registry for spans and compiles
 
     def __post_init__(self):
+        observe(self.metrics)
         self.n_pad, self.block_n, self.cap = mv_tiles(
             self.mesh, self.n, self.d, self.cap_frac)
         self._update = jax.jit(
@@ -605,35 +672,52 @@ class ShardedMultiViewHazy:
                            jnp.zeros(k, jnp.float32))
 
     def _do_reorg(self, state, W, b):
-        state = self._reorg(state, W, b)
+        with trace.span("round.reorganize", metrics=self.metrics):
+            state = self._reorg(state, jnp.asarray(W, jnp.float32),
+                                jnp.asarray(b, jnp.float32))
         self.skiing.record_reorg()
         self.lw[:] = 0.0
         self.hw[:] = 0.0
         return state
 
     def apply_models(self, state: ShardedMultiViewState, W, b):
-        """One eager round for all k views (modeled costs ∝ rows touched)."""
-        W = jnp.asarray(W, jnp.float32)
-        b32 = jnp.asarray(b, jnp.float32)
+        """One eager round for all k views (modeled costs ∝ rows touched).
+
+        Its spans, children of the caller's (`wal.commit` when served):
+        `round.fetch` pulls the stored model to the host (and so waits for
+        the device), `round.waters` is the Eq. 2 arithmetic, `round.update`
+        dispatches the band update, `round.sync` blocks on its overflow
+        flag, and `round.reorganize` dispatches a reorganize that SKIING or
+        an overflow forced. `round.update` counts `kernel_rounds` and
+        `round.reorganize` counts `skiing.reorgs`."""
+        m = self.metrics
         if self.skiing.should_reorganize():
-            return self._do_reorg(state, W, b32)
-        self.lw, self.hw = waters_update(
-            self.lw, self.hw, np.asarray(W), np.asarray(b, np.float64),
-            np.asarray(state.W_stored),
-            np.asarray(state.b_stored, np.float64), self.M, self.p)
-        state = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
-                               hw=jnp.asarray(self.hw, jnp.float32))
-        labels, wsum, overflow = self._update(state, W, b32)
-        state = state._replace(labels=labels)
-        self.kernel_rounds += 1
-        if int(overflow):
+            return self._do_reorg(state, W, b)
+        with trace.span("round.fetch", metrics=m):
+            W_s = np.asarray(state.W_stored)
+            b_s = np.asarray(state.b_stored, np.float64)
+        with trace.span("round.waters", metrics=m):
+            self.lw, self.hw = waters_update(
+                self.lw, self.hw, np.asarray(W, np.float32),
+                np.asarray(b, np.float64), W_s, b_s, self.M, self.p)
+        with trace.span("round.update", metrics=m):
+            W = jnp.asarray(W, jnp.float32)
+            b = jnp.asarray(b, jnp.float32)
+            state = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
+                                   hw=jnp.asarray(self.hw, jnp.float32))
+            labels, wsum, overflow = self._update(state, W, b)
+            state = state._replace(labels=labels)
+            self.kernel_rounds += 1
+        with trace.span("round.sync", metrics=m):
+            overflowed = int(overflow)
+            width = None if overflowed else float(np.sum(np.asarray(wsum)))
+        if overflowed:
             # some view's covering window outgrew the kernel capacity on
             # some shard: its labels past the capacity are stale — rebuild
             # the shared order instead of shipping them
             self.overflows += 1
-            return self._do_reorg(state, W, b32)
-        self.skiing.record_incremental(
-            float(np.sum(np.asarray(wsum))) / (self.n * self.k))
+            return self._do_reorg(state, W, b)
+        self.skiing.record_incremental(width / (self.n * self.k))
         return state
 
     def all_members(self, state) -> np.ndarray:
@@ -652,15 +736,22 @@ class ShardedMultiViewHazy:
         """§3.5.2 batched single-entity read: the device-side waters probe
         resolves what it can with zero feature bytes; the views that miss
         share ONE feature-row gather (the margin step). Returns
-        ((k,) int8 labels, (k,) bool resolved-by-water mask)."""
-        st = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
-                            hw=jnp.asarray(self.hw, jnp.float32))
-        lab, resolved, _ = self._probe(st, jnp.int32(entity_id))
-        lab = np.asarray(lab).copy()
-        resolved = np.asarray(resolved)
+        ((k,) int8 labels, (k,) bool resolved-by-water mask).
+
+        Spans: `read.probe` (the probe's dispatch and its two host copies)
+        and, only when some view misses, `read.margin` (the margin step,
+        which streams the table, and its host copy)."""
+        m = self.metrics
+        with trace.span("read.probe", metrics=m):
+            st = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
+                                hw=jnp.asarray(self.hw, jnp.float32))
+            lab, resolved, _ = self._probe(st, jnp.int32(entity_id))
+            lab = np.asarray(lab).copy()
+            resolved = np.asarray(resolved)
         if not resolved.all():
-            z = np.asarray(self._margin(st, jnp.asarray(W, jnp.float32),
-                                        jnp.asarray(b, jnp.float32),
-                                        jnp.int32(entity_id)))
-            lab = np.where(resolved, lab, classify(z)).astype(np.int8)
+            with trace.span("read.margin", metrics=m):
+                z = np.asarray(self._margin(
+                    st, jnp.asarray(W, jnp.float32),
+                    jnp.asarray(b, jnp.float32), jnp.int32(entity_id)))
+                lab = np.where(resolved, lab, classify(z)).astype(np.int8)
         return lab, resolved

@@ -17,7 +17,8 @@ from repro.obs.metrics import (
     Histogram,
     MetricsRegistry,
 )
-from repro.obs.trace import Span, Tracer, clock, current, finish, render_tree, span, start
+from repro.obs.trace import (Span, clock, current, finish, render_tree,
+                             set_mirror, span, start)
 from repro.obs.cost import ViewCostRecorder
 
 __all__ = [
@@ -28,12 +29,12 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "Span",
-    "Tracer",
     "ViewCostRecorder",
     "clock",
     "current",
     "finish",
     "render_tree",
+    "set_mirror",
     "span",
     "start",
 ]

@@ -13,6 +13,16 @@ an exception path is discarded rather than corrupting later statements.
 
 Rendered trees back EXPLAIN ANALYZE, the slow-statement log, and the REPL
 timing footer, so all three report the same per-phase breakdown.
+
+``set_mirror(factory)`` also writes every span onto a second timeline:
+``start`` opens ``factory(name)`` (a context manager, or None when nothing
+is being collected) and ``finish`` closes it. The device engine installs
+the profiler's host annotations here, so each span exists once on this
+clock (``span.<name>.seconds``) and once on the profiler's host timeline,
+which the device trace shares. A span the mirror annotated also feeds
+``profiled.span.<name>.seconds``: the spans of the profiled window alone,
+so a reader of a profile can count and time them without a snapshot taken
+when the profile began. This module stays stdlib-only.
 """
 from __future__ import annotations
 
@@ -20,7 +30,7 @@ import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 # The single sanctioned clock for the whole tree (TEL001: raw
 # time.perf_counter()/time.time() calls outside repro.obs are lint errors).
@@ -43,6 +53,8 @@ class Span:
     t1: Optional[float] = None
     attrs: Dict[str, Any] = field(default_factory=dict)
     children: List["Span"] = field(default_factory=list)
+    mirror: Any = None      # the open annotation on the mirror's timeline,
+                            # False once closed, None if it had none
 
     @property
     def duration_s(self) -> float:
@@ -84,6 +96,20 @@ def current() -> Optional[Span]:
 
 _new_span = object.__new__
 
+# name -> an annotation to open for the span, or None (see set_mirror)
+_mirror: Optional[Callable[[str], Any]] = None
+
+
+def set_mirror(factory: Optional[Callable[[str], Any]]
+               ) -> Optional[Callable[[str], Any]]:
+    """Mirror every span opened from now on: ``factory(name)`` returns a
+    context manager to enter at ``start`` and exit at ``finish``, or None
+    to skip that span. ``set_mirror(None)`` removes the mirror. Returns
+    the factory it replaces."""
+    global _mirror
+    previous, _mirror = _mirror, factory
+    return previous
+
 
 def start(name: str, **attrs: Any) -> Span:
     """Open a span as a child of this thread's current span (or a root)."""
@@ -99,28 +125,46 @@ def start(name: str, **attrs: Any) -> Span:
     if st:
         st[-1].children.append(sp)
     st.append(sp)
+    m = _mirror
+    sp.mirror = m(name) if m is not None else None
+    if sp.mirror is not None:
+        sp.mirror.__enter__()
     sp.t0 = clock()       # last: exclude our own setup from the interval
     return sp
 
 
-# span name -> "span.<name>.seconds", so the statement hot path doesn't
-# rebuild the histogram key on every finish.
-_hist_names: Dict[str, str] = {}
+def _close_mirror(sp: Span) -> None:
+    ann = sp.mirror
+    if ann is not None and ann is not False:
+        sp.mirror = False
+        ann.__exit__(None, None, None)
+
+
+# span name -> ("span.<name>.seconds", "profiled.span.<name>.seconds"), so
+# the statement hot path doesn't rebuild the histogram keys on every finish.
+_hist_names: Dict[str, tuple] = {}
 
 
 def finish(sp: Span, metrics: Any = None) -> Span:
-    """Close ``sp``: stamp t1, unwind the stack through it, record duration."""
+    """Close ``sp``: stamp t1, unwind the stack through it, record duration
+    (also as a profiled span if the mirror annotated it)."""
     sp.t1 = clock()
+    profiled = sp.mirror is not None
     st = _stack()
     while st:
         top = st.pop()
+        _close_mirror(top)    # orphans first: the mirror nests as we do
         if top is sp:
             break
+    _close_mirror(sp)
     if metrics is not None:
-        hname = _hist_names.get(sp.name)
-        if hname is None:
-            hname = _hist_names[sp.name] = f"span.{sp.name}.seconds"
-        metrics.histogram(hname).observe(sp.duration_s)
+        names = _hist_names.get(sp.name)
+        if names is None:
+            names = _hist_names[sp.name] = (
+                f"span.{sp.name}.seconds", f"profiled.span.{sp.name}.seconds")
+        metrics.histogram(names[0]).observe(sp.duration_s)
+        if profiled:
+            metrics.histogram(names[1]).observe(sp.duration_s)
     return sp
 
 
@@ -131,22 +175,6 @@ def span(name: str, metrics: Any = None, **attrs: Any) -> Iterator[Span]:
         yield sp
     finally:
         finish(sp, metrics)
-
-
-class Tracer:
-    """A span factory bound to one metrics registry."""
-
-    def __init__(self, metrics: Any = None) -> None:
-        self.metrics = metrics
-
-    def span(self, name: str, **attrs: Any):
-        return span(name, metrics=self.metrics, **attrs)
-
-    def start(self, name: str, **attrs: Any) -> Span:
-        return start(name, **attrs)
-
-    def finish(self, sp: Span) -> Span:
-        return finish(sp, self.metrics)
 
 
 def render_tree(sp: Span, indent: int = 0) -> str:

@@ -30,7 +30,6 @@ import os
 import threading
 from typing import Optional
 
-from repro.obs import trace
 from repro.rdbms.ast_nodes import SqlError
 from repro.rdbms.executor import Executor, Result, Session
 from repro.rdbms.wire import (WireError, decode_payload, encode_frame,
@@ -155,15 +154,13 @@ class SqlServer:
                 return {"ok": True, "refreshed": refreshed,
                         "epoch": self.executor.epoch,
                         "session": session.session_id}
-            with trace.span("request", metrics=self.executor.metrics,
-                            op=op):
-                if op == "query":
-                    results = session.execute(request["sql"])
-                elif op == "execute":
-                    results = [session.execute_prepared(
-                        request["name"], request.get("params", ()))]
-                else:
-                    raise SqlError(f"unknown op {op!r}")
+            if op == "query":
+                results = session.execute(request["sql"])
+            elif op == "execute":
+                results = [session.execute_prepared(
+                    request["name"], request.get("params", ()))]
+            else:
+                raise SqlError(f"unknown op {op!r}")
             self.statements_served += len(results)
             if self.log_statements:
                 for r in results:

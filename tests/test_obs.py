@@ -180,6 +180,76 @@ def test_span_records_into_registry():
     assert reg.histogram("span.phase.seconds").count == 1
 
 
+class _Recorder:
+    """A span mirror that logs each annotation's enter and exit."""
+
+    def __init__(self, skip=()):
+        self.log, self.skip = [], set(skip)
+
+    def __call__(self, name):
+        if name in self.skip:
+            return None
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                rec.log.append(("exit", name))
+        return _Ann()
+
+
+def test_span_mirror_nests_and_unwinds_through_orphans():
+    """The mirror opens at start and closes at finish, innermost first,
+    also for children an exception path left open; a factory that returns
+    None skips that span; set_mirror returns what it replaced."""
+    rec = _Recorder(skip={"quiet"})
+    previous = trace.set_mirror(rec)
+    try:
+        with trace.span("root"):
+            with trace.span("child"):
+                pass
+            with trace.span("quiet"):
+                pass
+            trace.start("orphan")
+        assert trace.set_mirror(rec) is rec
+    finally:
+        trace.set_mirror(previous)
+    assert rec.log == [("enter", "root"), ("enter", "child"),
+                       ("exit", "child"), ("enter", "orphan"),
+                       ("exit", "orphan"), ("exit", "root")]
+    with trace.span("unmirrored") as sp:
+        pass
+    assert sp.mirror is None and len(rec.log) == 6
+
+
+def test_mirrored_spans_also_feed_the_profiled_histograms():
+    """A span the mirror annotated (the orphan too) is recorded twice:
+    `span.<name>.seconds` and `profiled.span.<name>.seconds`; one the
+    mirror skipped, or opened with no mirror, only in the first."""
+    reg = MetricsRegistry()
+    previous = trace.set_mirror(_Recorder(skip={"quiet"}))
+    try:
+        with trace.span("root", metrics=reg):
+            with trace.span("quiet", metrics=reg):
+                pass
+            orphan = trace.start("orphan")
+        trace.finish(orphan, reg)
+    finally:
+        trace.set_mirror(previous)
+    with trace.span("root", metrics=reg):
+        pass
+    hist = reg.snapshot()["histograms"]
+    count = {n: h["count"] for n, h in hist.items()}
+    assert count == {"span.root.seconds": 2, "span.quiet.seconds": 1,
+                     "span.orphan.seconds": 1,
+                     "profiled.span.root.seconds": 1,
+                     "profiled.span.orphan.seconds": 1}
+    assert hist["profiled.span.root.seconds"]["sum"] <= \
+        hist["span.root.seconds"]["sum"]
+
+
 def test_render_tree_shape():
     with trace.span("a", kind="x") as a:
         with trace.span("b"):
@@ -439,3 +509,139 @@ def test_telemetry_overhead_is_bounded():
         h.observe(1e-4)
     per_op = (clock() - t0) / 10000
     assert per_op < 50e-6                  # generous: CI boxes are noisy
+
+
+# ---------------------------------------------------------------------------
+# the device engine's spans, programs and compile counters
+# ---------------------------------------------------------------------------
+
+def _sharded_executor(n=256, d=16, k=4, rounds=10, seed=9):
+    """A served-path stack over engine = sharded on a (1, 1) CPU mesh: the
+    driver installs the profiler mirror and counts compiles in the
+    catalog's registry. Runs `rounds` group commits, each followed by
+    point reads of every 16th entity."""
+    pytest.importorskip("jax")
+    from repro.data import multiclass_corpus
+    c = multiclass_corpus("obs", n, d, k, seed=seed)
+    catalog = Catalog()
+    catalog.register_table("t", c.features, truth=c.classes, num_classes=k)
+    catalog.create_view("v", "t", "svm", {"engine": "sharded", "k": k,
+                                          "p": 2, "q": 2, "lr": 0.5,
+                                          "cap_frac": 0.5})
+    ex = Executor(catalog, group_commit=8)
+    rng = np.random.default_rng(seed)
+
+    def drive():
+        for _ in range(rounds):
+            ids = rng.integers(0, n, 8)
+            ex.execute_one("INSERT INTO t (id, class) VALUES " + ", ".join(
+                f"({int(i)}, {int(c.classes[i])})" for i in ids))
+            for i in range(0, n, 16):
+                ex.execute_one(f"SELECT id, view, label FROM v WHERE id = {i}")
+    return ex, catalog.view("v").facade, drive
+
+
+def test_round_and_read_span_counts_reconcile_with_driver_counters():
+    ex, f, drive = _sharded_executor()
+    drive()
+    d = f.driver
+    hist = ex.metrics_snapshot()["histograms"]
+
+    def count(name):
+        return hist.get(f"span.{name}.seconds", {"count": 0})["count"]
+    assert d.kernel_rounds > d.overflows and d.skiing.reorgs > 0
+    assert f.disk_touches > 0
+    assert count("round.update") == d.kernel_rounds
+    assert count("round.sync") == d.kernel_rounds
+    assert count("round.reorganize") == d.skiing.reorgs
+    assert count("read.margin") == f.disk_touches
+    assert count("read.probe") == 16 * 10
+    assert count("round.sgd") == ex.log.commits == 10
+    # a round either dispatches the band update or SKIING reorganizes it
+    assert count("round.fetch") == count("round.waters") == d.kernel_rounds
+    assert d.kernel_rounds + d.skiing.reorgs - d.overflows == 10
+
+
+def test_profiler_trace_holds_nested_statement_and_round_spans(tmp_path):
+    """With the mirror installed (by the driver), a profile collected on
+    the CPU holds the statement spans and the round.* and read.* spans,
+    each inside its parent on the same thread."""
+    jax = pytest.importorskip("jax")
+    ex, f, drive = _sharded_executor(rounds=4)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        drive()
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    parent = {"statement": None, "execute": "statement",
+              "wal.commit": "execute", "probe": "execute",
+              "round.sgd": "wal.commit", "round.fetch": "wal.commit",
+              "round.waters": "wal.commit", "round.update": "wal.commit",
+              "round.sync": "wal.commit", "round.reorganize": "wal.commit",
+              "read.probe": "probe", "read.margin": "probe"}
+    seen = {}
+    for plane in data.planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for e in line.events if e.name in parent]
+            for name, s, e in evs:
+                seen[name] = seen.get(name, 0) + 1
+                up = parent[name]
+                if up is not None:
+                    assert any(n == up and ps <= s and e <= pe
+                               for n, ps, pe in evs), (name, s)
+    assert {"statement", "wal.commit", "round.sgd", "round.update",
+            "round.sync", "read.probe"} <= set(seen)
+    assert seen["round.update"] + seen.get("round.reorganize", 0) >= 4
+    # the driver's spans of the profiled window, counted by the program
+    hist = ex.metrics_snapshot()["histograms"]
+    for name in ("round.sgd", "round.update", "round.sync", "read.probe"):
+        assert hist[f"profiled.span.{name}.seconds"]["count"] == seen[name]
+    assert "profiled.span.read.probe.seconds" not in \
+        _sharded_executor(rounds=1)[0].metrics_snapshot()["histograms"]
+
+
+def test_programs_carry_their_names_in_compile_events():
+    """Every jitted program of the drivers compiles under its own name,
+    and the driver counts those compiles in its registry."""
+    jax = pytest.importorskip("jax")
+    from repro.core import sharded
+    from repro.core.waters import holder_M
+    from repro.launch.mesh import make_host_mesh
+
+    names = []
+
+    def listen(event, duration, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            names.append(kw.get("fun_name"))
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        rng = np.random.default_rng(5)
+        n, d, k = 256, 16, 3
+        F = rng.random((n, d), dtype=np.float32)
+        reg = MetricsRegistry()
+        drv = sharded.ShardedMultiViewHazy(
+            mesh=make_host_mesh((1, 1)), n=n, d=d, k=k, M=holder_M(F, 2.0),
+            cap_frac=0.5, metrics=reg)
+        state = drv.init_state(F)
+        W = rng.normal(size=(k, d)).astype(np.float32) * 0.01
+        b = np.zeros(k)
+        state = drv.apply_models(state, W, b)
+        drv.lw[:], drv.hw[:] = -1e9, 1e9      # every view misses
+        drv.hybrid_labels_of(state, W, b, 3)
+        drv.all_members(state)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    want = {"band_update", "reorganize", "probe", "margin", "all_members"}
+    assert {f"jit({p})" for p in want} <= set(names)
+    counters = reg.snapshot()["counters"]
+    assert all(counters[f"compiles.{p}"] >= 1 for p in want)
+    assert reg.histogram("compiles.band_update.seconds").sum > 0
+    mesh = make_host_mesh((1, 1))
+    assert [sharded.make_naive_update_step(mesh).__name__,
+            sharded.make_hazy_update_step(mesh, n)[0].__name__,
+            sharded.make_reorganize_step(mesh).__name__,
+            sharded.make_all_members_step(mesh).__name__] == [
+        "naive_update", "band_update", "reorganize", "all_members"]
