@@ -47,7 +47,7 @@ CUT = ("cut: hash width 4096 -> 1024 features; at 4096 the f32 table is "
        "to move a decision boundary within a few hundred inserts).")
 TOL = 1e-4                # |reference margin| at or below this may round
                           # to either sign between summation orders
-VIEW_OPTS = dict(lr=1.0, l2=1e-4, p=2.0, q=2.0, alpha=1.0, cap_frac=0.5)
+VIEW_OPTS = dict(lr=1.0, l2=1e-4, p=2.0, q=2.0, alpha=1.0)
 ROUNDS, GROUP = 24, 16    # maintenance rounds x inserts per group commit
 REHEARSAL_SCALE = 0.001   # --cpu-rehearsal: 1,000 rows
 
@@ -181,8 +181,8 @@ def serve_and_check(scale: float, rounds: int, group: int, seed: int,
                   f"device + first reorganize, compile included)")
             driver = catalog.view("topics").facade.driver
             print(f"layout: n_pad = {driver.n_pad:,}, block_n = "
-                  f"{driver.block_n}, kernel window cap = {driver.cap:,} "
-                  f"rows, mesh {dict(driver.mesh.shape)}")
+                  f"{driver.block_n}, rows a shard = {driver.cap:,}, "
+                  f"mesh {dict(driver.mesh.shape)}")
 
             round_s = []
             for r, ids in enumerate(stream):
@@ -194,12 +194,12 @@ def serve_and_check(scale: float, rounds: int, group: int, seed: int,
                 round_s.append(time.perf_counter() - t)
                 print(f"round {r}: {len(ids)} inserts, {round_s[-1]:.4f} s, "
                       f"kernel rounds {driver.kernel_rounds}, reorganizes "
-                      f"{driver.skiing.reorgs}, overflows "
-                      f"{driver.overflows}, compiles {ct.count - compiles}")
-            kernel_ok = driver.kernel_rounds - driver.overflows
+                      f"{driver.skiing.reorgs}, compiles "
+                      f"{ct.count - compiles}")
+            kernel_ok = driver.kernel_rounds
             print(f"maintenance: {len(stream)} rounds of {group} inserts; "
-                  f"kernel rounds {kernel_ok} (+{driver.overflows} "
-                  f"overflowed), reorganizes {driver.skiing.reorgs}; "
+                  f"kernel rounds {kernel_ok}, reorganizes "
+                  f"{driver.skiing.reorgs}; "
                   f"seconds per round: first {round_s[0]:.4f}, median of "
                   f"the rest {float(np.median(round_s[1:])):.4f}, max "
                   f"{max(round_s[1:]):.4f}")
@@ -291,7 +291,7 @@ def four_chips(scale: float, rounds: int, group: int, seed: int,
     print(f"table: n = {n:,} real rows, d = {d}, k = {K}")
     M = holder_M(F, VIEW_OPTS["q"])
     kw = dict(n=n, d=d, k=K, M=M, p=VIEW_OPTS["p"],
-              alpha=VIEW_OPTS["alpha"], cap_frac=VIEW_OPTS["cap_frac"])
+              alpha=VIEW_OPTS["alpha"])
     runs = {"4 chips": ShardedMultiViewHazy(
                 mesh=make_mesh((4, 1), ("data", "model")), **kw),
             "1 chip": ShardedMultiViewHazy(
@@ -303,8 +303,8 @@ def four_chips(scale: float, rounds: int, group: int, seed: int,
         shards = [(s.device.id, s.data.shape)
                   for s in states[name].F.addressable_shards]
         print(f"{name}: F shards {shards}, init {time.perf_counter() - t:.2f} "
-              f"s; n_pad = {dr.n_pad:,}, block_n = {dr.block_n}, cap = "
-              f"{dr.cap:,}")
+              f"s; n_pad = {dr.n_pad:,}, block_n = {dr.block_n}, rows a "
+              f"shard = {dr.cap:,}")
     check(len({s.device for s in states["4 chips"].F.addressable_shards})
           == 4, "the 4-chip table is not spread over four devices")
 
@@ -325,9 +325,9 @@ def four_chips(scale: float, rounds: int, group: int, seed: int,
     Z = reference_margins(F, W, b)
     got = {}
     for name, dr in runs.items():
-        kernel_ok = dr.kernel_rounds - dr.overflows
-        print(f"{name}: kernel rounds {kernel_ok} (+{dr.overflows} "
-              f"overflowed), reorganizes {dr.skiing.reorgs}, counts "
+        kernel_ok = dr.kernel_rounds
+        print(f"{name}: kernel rounds {kernel_ok}, reorganizes "
+              f"{dr.skiing.reorgs}, counts "
               f"{dr.all_members(states[name]).tolist()}")
         check(kernel_ok >= 3, f"{name}: fewer than 3 kernel rounds")
         gids, labels, _ = dr.real_rows(states[name])

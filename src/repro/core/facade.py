@@ -565,10 +565,10 @@ class ShardedFacade(EngineFacade):
 
     def point_labels_of(self, entity_id):
         labels, resolved = self.driver.hybrid_labels_of(
-            self.state, self.W, self.b, int(entity_id))
+            self.state, int(entity_id))
         hows = ["water" if r else "disk" for r in resolved]
         if not bool(np.asarray(resolved).all()):
-            self._disk += 1            # ONE shared feature-row gather
+            self._disk += 1            # the missed views took the feature row
         for h in hows:
             self.tier_hits[h] += 1
         return labels, hows
@@ -641,8 +641,8 @@ class ShardedFacade(EngineFacade):
 
 def make_sharded_facade(features: np.ndarray, k: int, *, p: float = 2.0,
                         q: float = 2.0, lr: float = 0.1, l2: float = 1e-4,
-                        alpha: float = 1.0, cap_frac: float = 0.5,
-                        mesh=None, metrics=None) -> ShardedFacade:
+                        alpha: float = 1.0, mesh=None,
+                        metrics=None) -> ShardedFacade:
     """Build a `ShardedFacade` on `mesh` (default: single-host (1, 1)); its
     spans and compile counts go to the registry `metrics`."""
     from repro.core.sharded import ShardedMultiViewHazy
@@ -653,6 +653,5 @@ def make_sharded_facade(features: np.ndarray, k: int, *, p: float = 2.0,
     F = np.ascontiguousarray(features, np.float32)
     driver = ShardedMultiViewHazy(
         mesh=mesh, n=F.shape[0], d=F.shape[1], k=int(k),
-        M=holder_M(F, q), p=p, alpha=alpha, cap_frac=cap_frac,
-        metrics=metrics)
+        M=holder_M(F, q), p=p, alpha=alpha, metrics=metrics)
     return ShardedFacade(driver, F, lr=lr, l2=l2)

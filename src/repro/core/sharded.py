@@ -28,21 +28,23 @@ every view's band is clustered near the front of the shard). The order is
 maintained entirely device-side: the reorganize step re-sorts it, the
 update step computes per-view covering windows of the Lemma 3.1 band in
 that order (`engine.covering_windows`) and relabels the union of the k
-windows with ONE `multiview_band_reclassify` Pallas launch — no vmapped
-per-view dynamic slices. The kernel computes sign(w_v·f − b_v) from whole
-feature rows, so the scratch table is row-sharded and model-REPLICATED
-(the (k, d) models are tiny; the big model-sharded training jobs live in
-models/steps.py). The §3.5.2 hybrid read pair rides the same state:
-`make_multiview_hybrid_probe_step` (eps-map lookup + waters short-circuit,
-zero feature bytes) and `make_multiview_entity_margin_step` (ONE shared
-feature-row gather for the views the waters cannot resolve).
+windows with ONE `multiview_band_reclassify` Pallas launch, which streams
+each tile of that union once for all k views — no vmapped per-view dynamic
+slices, and no capacity: a window may span the whole shard, so SKIING
+alone decides when to reorganize. The kernel computes sign(w_v·f − b_v)
+from whole feature rows, so the scratch table is row-sharded and
+model-REPLICATED (the (k, d) models are tiny; the big model-sharded
+training jobs live in models/steps.py). The §3.5.2 hybrid read rides
+the same state: `make_multiview_hybrid_probe_step` looks the entity up in
+the eps-map, resolves what the waters can, and labels the views they
+cannot from the entity's one feature row, all in one program.
 
-Static band capacity: jit needs static shapes, so bands are processed
-through a `cap`-row window per shard (cap ≈ n_shard * cap_frac, tile
-aligned). The kernel reports per-view window overflow
-(`with_overflow=True`) and the host driver triggers reorganization instead
-of shipping the stale labels a truncated window would leave behind —
-SKIING would usually have reorganized long before that.
+Static band capacity (single view only): jit needs static shapes, so
+`hazy_update_step` processes the band through a `cap`-row window per shard
+(cap ≈ n_shard * cap_frac) and the host driver reorganizes when the band
+outgrows it. The multi-view kernel walks all of a shard's tiles with its
+index maps clamped into the union window, so its shapes are static without
+a capacity.
 
 Observation: every jitted program is named for what it does (`PROGRAMS`),
 so the profiler's "XLA Modules" line and JAX's compile events say which
@@ -122,7 +124,7 @@ def _specs(mesh: Mesh):
 
 # the drivers' jitted programs, by the name each step function carries: the
 # trace's "XLA Modules" line shows `jit_<name>`, compile events `jit(<name>)`
-PROGRAMS = ("band_update", "reorganize", "probe", "margin", "all_members",
+PROGRAMS = ("band_update", "reorganize", "probe", "all_members",
             "naive_update")
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _watching: "weakref.WeakSet" = weakref.WeakSet()   # registries counting
@@ -396,6 +398,8 @@ PAD_GID = -1                # gid of a padding row of the scratch table
 # bytes of one f32 (block_n, d) tile of F in VMEM; the kernel pipeline holds
 # two of them, which stays inside the smallest default scoped-VMEM limit
 VMEM_TILE_BYTES = 2 << 20
+# `band.window_rows` buckets: 128 rows (one tile) .. 2**26 rows
+WINDOW_ROW_BUCKETS = tuple(float(2 ** e) for e in range(7, 27))
 
 
 def multiview_state_specs(n_pad: int, d: int, k: int, mesh: Mesh,
@@ -428,24 +432,22 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def mv_tiles(mesh: Mesh, n: int, d: int, cap_frac: float):
-    """(n_pad, block_n, cap) for the band kernel.
+def mv_tiles(mesh: Mesh, n: int, d: int):
+    """(n_pad, block_n, n_local) for the band kernel.
 
     block_n is a multiple of 128 (the TPU lane tile of the (1, block_n)
     label blocks) sized so that one f32 (block_n, d) tile of F, lanes padded
     to 128, fits `VMEM_TILE_BYTES`, and no larger than a shard needs. Each
     row shard holds n_local rows: its share of the n real rows padded up to
     a multiple of block_n, so the table holds n_pad = n_local * shards rows
-    (the padding sits at the end). cap is the per-shard kernel window,
-    tile-aligned in [block_n, n_local]."""
+    (the padding sits at the end)."""
     rows = _row_axes(mesh)
     n_shards = int(np.prod([mesh.shape[a] for a in rows])) if rows else 1
     per_shard = -(-n // n_shards)
     block_n = VMEM_TILE_BYTES // (4 * _round_up(d, 128)) // 128 * 128
     block_n = min(max(128, block_n), _round_up(per_shard, 128))
     n_local = _round_up(per_shard, block_n)
-    cap = _round_up(max(block_n, int(n_local * cap_frac)), block_n)
-    return n_local * n_shards, block_n, min(cap, n_local)
+    return n_local * n_shards, block_n, n_local
 
 
 def kernel_interpret(mesh: Mesh) -> bool:
@@ -458,37 +460,34 @@ def kernel_interpret(mesh: Mesh) -> bool:
     return platform == "cpu"
 
 
-def make_multiview_update_step(mesh: Mesh, block_n: int, cap: int):
+def make_multiview_update_step(mesh: Mesh, block_n: int):
     """Banded incremental step for all k views in ONE Pallas launch.
 
     Per shard: `engine.covering_windows` locates each view's covering
     window of the Lemma 3.1 band in the shared order (pure device compute,
     no per-view dynamic slices), then `multiview_band_reclassify` streams
-    only the union of the k windows HBM->VMEM and relabels them under the
-    stacked models. Returns (labels', true band widths (k,), overflow flag
-    () i32 — nonzero when some view's window exceeded the kernel capacity
-    on some shard, i.e. rows past the capacity kept stale labels and the
-    driver must reorganize)."""
+    the union of the k windows HBM->VMEM once and relabels each view's
+    window under the stacked models. Returns (labels', counts (k + 1,)
+    i32): the true band width of each view, then the rows the kernel
+    streamed, each summed over shards — one array, so one host copy."""
     pf, pr, pkr = _mv_specs(mesh)
     rows = _row_axes(mesh)
     interpret = kernel_interpret(mesh)
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b):
         start, end, width = covering_windows(eps, lw, hw, xp=jnp)
-        labels, overflow = multiview_band_reclassify(
-            F, labels, W, b, start, end, cap=cap, block_n=block_n,
-            interpret=interpret, with_overflow=True)
-        wsum = width
-        ov = jnp.any(overflow).astype(jnp.int32)
+        labels, streamed = multiview_band_reclassify(
+            F, labels, W, b, start, end, block_n=block_n,
+            interpret=interpret)
+        counts = jnp.concatenate([width, streamed[None].astype(jnp.int32)])
         for ax in rows:
-            wsum = jax.lax.psum(wsum, ax)
-            ov = jax.lax.pmax(ov, ax)
-        return labels, wsum, ov
+            counts = jax.lax.psum(counts, ax)
+        return labels, counts
 
     fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P()),
-        out_specs=(pkr, P(), P()),
+        out_specs=(pkr, P()),
         check_vma=False)     # pallas_call outputs carry no varying-axes type
 
     def band_update(state: ShardedMultiViewState, W, b):
@@ -538,60 +537,43 @@ def make_multiview_reorganize_step(mesh: Mesh):
 
 
 def make_multiview_hybrid_probe_step(mesh: Mesh):
-    """§3.5.2 waters short-circuit for ONE entity across all k views with
-    ZERO feature-table bytes: the entity's stored eps per view comes from
-    the eps-map (masked row-shard sum over the shared `gids`, psum'd), and
-    the waters test is THE shared Lemma 3.1 point-probe
-    (engine.probe_partition). Returns (labels (k,) int8 with 0 =
-    unresolved, resolved (k,) bool, eps_e (k,))."""
-    pf, pr, pkr = _mv_specs(mesh)
-    rows = _row_axes(mesh)
-
-    def local(F, gids, eps, labels, W_s, b_s, lw, hw, eid):
-        hit = gids == eid                    # entity appears once globally
-        e = jnp.sum(jnp.where(hit[None, :], eps, 0.0), axis=1)
-        for ax in rows:
-            e = jax.lax.psum(e, ax)
-        lab = probe_partition(e, lw, hw, xp=jnp)
-        return lab, lab != 0, e
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P()),
-        out_specs=(P(), P(), P()))
-
-    def probe(state: ShardedMultiViewState, entity_id):
-        return fn(*state, entity_id)
-
-    return probe
-
-
-def make_multiview_entity_margin_step(mesh: Mesh):
-    """The "disk" fallback for views the waters cannot short-circuit: ONE
-    gather of the entity's feature row (masked row-shard sum), then every
-    view's margin from the stacked models — one shared F touch for all k
-    views that miss. Returns z (k,) f32 (margins, bias subtracted)."""
+    """§3.5.2 hybrid read of ONE entity across all k views, in one program:
+    the entity's stored eps per view comes from the eps-map (masked
+    row-shard sum over the shared `gids`, psum'd), and the waters test is
+    THE shared Lemma 3.1 point-probe (engine.probe_partition); the views
+    it leaves unresolved take their label from the entity's margins under
+    the current models (W, b), computed from its one feature row (found
+    by its position in the shard's `gids`). The row is gathered whatever
+    the waters say: it is one row, and a second program would cost a
+    dispatch and a host copy. Returns (2, k) int8: the labels, then 1
+    where the waters resolved the view — one array, so one host copy."""
     pf, pr, pkr = _mv_specs(mesh)
     rows = _row_axes(mesh)
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b, eid):
-        hit = (gids == eid).astype(jnp.float32)           # (n_local,)
-        f = jnp.einsum("n,nd->d", hit, F.astype(jnp.float32),
-                       precision=HIGHEST)
+        hit = gids == eid                    # entity appears once globally
+        e = jnp.sum(jnp.where(hit[None, :], eps, 0.0), axis=1)
+        row = jax.lax.dynamic_index_in_dim(F, jnp.argmax(hit), 0,
+                                           keepdims=False)
+        f = jnp.where(jnp.any(hit), row.astype(jnp.float32), 0.0)
         z = jnp.einsum("kd,d->k", W, f, precision=HIGHEST)
         for ax in rows:            # other row shards contribute exact zeros
+            e = jax.lax.psum(e, ax)
             z = jax.lax.psum(z, ax)
-        return z - b
+        probed = probe_partition(e, lw, hw, xp=jnp)     # 0 = unresolved
+        resolved = probed != 0
+        lab = jnp.where(resolved, probed, classify(z - b, xp=jnp))
+        return jnp.stack([lab, resolved.astype(jnp.int8)])
 
     fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(pf, pr, pkr, pkr, P(), P(), P(), P(), P(), P(), P()),
         out_specs=P())
 
-    def margin(state: ShardedMultiViewState, W, b, entity_id):
+    def probe(state: ShardedMultiViewState, W, b, entity_id):
         return fn(*state, W, b, entity_id)
 
-    return margin
+    return probe
 
 
 def make_multiview_all_members_step(mesh: Mesh):
@@ -619,9 +601,11 @@ class ShardedMultiViewHazy:
     op), per-view Hölder waters kept host-side via `engine.waters_update`.
     `apply_models` reclassifies the union band through the
     `multiview_band_reclassify` kernel against the device-resident shared
-    clustering order, and falls back to reorganization whenever the kernel
-    reports a covering-window overflow (stale labels would ship otherwise).
-    `n` counts real entity rows; the device table holds `n_pad` rows."""
+    clustering order; SKIING alone decides when to reorganize. `n` counts
+    real entity rows; the device table holds `n_pad` rows, `cap` of them
+    on each shard — the widest window the kernel takes. In its metrics
+    registry the histogram `band.window_rows` takes, once a launch, the
+    rows the kernel streamed (summed over shards)."""
     mesh: Mesh
     n: int
     d: int
@@ -629,25 +613,30 @@ class ShardedMultiViewHazy:
     M: float
     p: float = 2.0
     alpha: float = 1.0
-    cap_frac: float = 1 / 64
     metrics: Optional[Any] = None     # registry for spans and compiles
 
     def __post_init__(self):
         observe(self.metrics)
         self.n_pad, self.block_n, self.cap = mv_tiles(
-            self.mesh, self.n, self.d, self.cap_frac)
+            self.mesh, self.n, self.d)
         self._update = jax.jit(
-            make_multiview_update_step(self.mesh, self.block_n, self.cap))
+            make_multiview_update_step(self.mesh, self.block_n))
         self._reorg = jax.jit(make_multiview_reorganize_step(self.mesh))
         self._count = jax.jit(make_multiview_all_members_step(self.mesh))
         self._probe = jax.jit(make_multiview_hybrid_probe_step(self.mesh))
-        self._margin = jax.jit(make_multiview_entity_margin_step(self.mesh))
         from repro.core.skiing import Skiing
         self.skiing = Skiing(S=1.0, alpha=self.alpha)
         self.lw = np.zeros(self.k, np.float64)
         self.hw = np.zeros(self.k, np.float64)
+        # the last round's (W, b) on the device: point reads label under
+        # them without copying the models to the device again
+        self.models = (jnp.zeros((self.k, self.d), jnp.float32),
+                       jnp.zeros(self.k, jnp.float32))
         self.kernel_rounds = 0    # update-step launches of the band kernel
-        self.overflows = 0        # kernel-capacity overflow -> forced reorg
+        self.overflows = 0        # always 0: no kernel capacity to overflow
+        self._window_rows = (None if self.metrics is None else
+                             self.metrics.histogram("band.window_rows",
+                                                    WINDOW_ROW_BUCKETS))
 
     def init_state(self, F: np.ndarray) -> ShardedMultiViewState:
         k, n, n_pad = self.k, self.n, self.n_pad
@@ -673,8 +662,9 @@ class ShardedMultiViewHazy:
 
     def _do_reorg(self, state, W, b):
         with trace.span("round.reorganize", metrics=self.metrics):
-            state = self._reorg(state, jnp.asarray(W, jnp.float32),
-                                jnp.asarray(b, jnp.float32))
+            self.models = (jnp.asarray(W, jnp.float32),
+                           jnp.asarray(b, jnp.float32))
+            state = self._reorg(state, *self.models)
         self.skiing.record_reorg()
         self.lw[:] = 0.0
         self.hw[:] = 0.0
@@ -686,9 +676,9 @@ class ShardedMultiViewHazy:
         Its spans, children of the caller's (`wal.commit` when served):
         `round.fetch` pulls the stored model to the host (and so waits for
         the device), `round.waters` is the Eq. 2 arithmetic, `round.update`
-        dispatches the band update, `round.sync` blocks on its overflow
-        flag, and `round.reorganize` dispatches a reorganize that SKIING or
-        an overflow forced. `round.update` counts `kernel_rounds` and
+        dispatches the band update, `round.sync` blocks on its band widths
+        and streamed rows, and `round.reorganize` dispatches a reorganize
+        that SKIING called. `round.update` counts `kernel_rounds` and
         `round.reorganize` counts `skiing.reorgs`."""
         m = self.metrics
         if self.skiing.should_reorganize():
@@ -701,23 +691,19 @@ class ShardedMultiViewHazy:
                 self.lw, self.hw, np.asarray(W, np.float32),
                 np.asarray(b, np.float64), W_s, b_s, self.M, self.p)
         with trace.span("round.update", metrics=m):
-            W = jnp.asarray(W, jnp.float32)
-            b = jnp.asarray(b, jnp.float32)
+            self.models = (jnp.asarray(W, jnp.float32),
+                           jnp.asarray(b, jnp.float32))
             state = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
                                    hw=jnp.asarray(self.hw, jnp.float32))
-            labels, wsum, overflow = self._update(state, W, b)
+            labels, counts = self._update(state, *self.models)
             state = state._replace(labels=labels)
             self.kernel_rounds += 1
         with trace.span("round.sync", metrics=m):
-            overflowed = int(overflow)
-            width = None if overflowed else float(np.sum(np.asarray(wsum)))
-        if overflowed:
-            # some view's covering window outgrew the kernel capacity on
-            # some shard: its labels past the capacity are stale — rebuild
-            # the shared order instead of shipping them
-            self.overflows += 1
-            return self._do_reorg(state, W, b)
-        self.skiing.record_incremental(width / (self.n * self.k))
+            counts = np.asarray(counts)
+        if self._window_rows is not None:
+            self._window_rows.observe(int(counts[-1]))
+        self.skiing.record_incremental(
+            float(np.sum(counts[:-1])) / (self.n * self.k))
         return state
 
     def all_members(self, state) -> np.ndarray:
@@ -731,27 +717,19 @@ class ShardedMultiViewHazy:
         return (gids[real], np.asarray(state.labels)[:, real],
                 np.asarray(state.eps)[:, real])
 
-    def hybrid_labels_of(self, state: ShardedMultiViewState, W, b,
+    def hybrid_labels_of(self, state: ShardedMultiViewState,
                          entity_id: int):
-        """§3.5.2 batched single-entity read: the device-side waters probe
-        resolves what it can with zero feature bytes; the views that miss
-        share ONE feature-row gather (the margin step). Returns
-        ((k,) int8 labels, (k,) bool resolved-by-water mask).
+        """§3.5.2 batched single-entity read under the last round's models
+        (`models`): the device-side waters probe resolves what it can from
+        the eps-map; the views that miss are labelled from the entity's
+        one feature row, in the same program. Returns ((k,) int8 labels,
+        (k,) bool resolved-by-water mask).
 
-        Spans: `read.probe` (the probe's dispatch and its two host copies)
-        and, only when some view misses, `read.margin` (the margin step,
-        which streams the table, and its host copy)."""
-        m = self.metrics
-        with trace.span("read.probe", metrics=m):
+        Span: `read.probe` (the program's dispatch and its one host
+        copy)."""
+        with trace.span("read.probe", metrics=self.metrics):
             st = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
                                 hw=jnp.asarray(self.hw, jnp.float32))
-            lab, resolved, _ = self._probe(st, jnp.int32(entity_id))
-            lab = np.asarray(lab).copy()
-            resolved = np.asarray(resolved)
-        if not resolved.all():
-            with trace.span("read.margin", metrics=m):
-                z = np.asarray(self._margin(
-                    st, jnp.asarray(W, jnp.float32),
-                    jnp.asarray(b, jnp.float32), jnp.int32(entity_id)))
-                lab = np.where(resolved, lab, classify(z)).astype(np.int8)
-        return lab, resolved
+            out = np.asarray(self._probe(st, *self.models,
+                                         jnp.int32(entity_id)))
+        return out[0], out[1].astype(bool)

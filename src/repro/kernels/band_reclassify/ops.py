@@ -11,32 +11,28 @@ from repro.kernels.band_reclassify.ref import band_reclassify_ref  # noqa: F401
 
 
 def multiview_band_reclassify(F, labels, W, b, start_rows, end_rows, *,
-                              cap: int = 4096, block_n: int = 512,
-                              interpret: bool = False,
-                              with_overflow: bool = False):
+                              block_n: int = 512, interpret: bool = False):
     """Relabel rows [start_rows[v], end_rows[v]) of the shared scratch
     table under each view's model (W[v], b[v]) in ONE kernel launch.
 
-    labels: (k, n) int8, rows aligned to F's row order. Windows are
-    tile-aligned and capacity-clamped per view: a view whose aligned window
-    end_rows[v] − aligned_start[v] exceeds `cap` is silently truncated, so
-    rows past the capacity keep STALE labels. `with_overflow=True`
-    additionally returns the per-view (k,) bool truncation flag so the
-    SKIING driver can trigger reorganization instead of shipping those
-    stale labels (the sharded multi-view update step does exactly that)."""
+    labels: (k, n) int8, rows aligned to F's row order. The kernel streams
+    the union of the k windows, rounded out to block_n tiles, once; a view
+    with an empty window (end ≤ start) relabels nothing. A window may span
+    the whole table: there is no capacity to truncate it. Returns
+    (labels', rows streamed). Where every window is empty the kernel still
+    streams one tile and writes it back unchanged."""
     n, d = F.shape
-    start_rows = jnp.asarray(start_rows, jnp.int32)
-    end_rows = jnp.asarray(end_rows, jnp.int32)
-    start_blocks = jnp.clip(start_rows // block_n, 0,
-                            max(0, (n - cap) // block_n))
-    requested = end_rows - start_blocks * block_n
-    widths = jnp.clip(requested, 0, cap)
-    out = _mv_kernel(F, labels, W, jnp.asarray(b, jnp.float32),
-                     start_blocks, widths, cap=cap, block_n=block_n,
+    n_tiles = n // block_n
+    start_rows = jnp.clip(jnp.asarray(start_rows, jnp.int32), 0, n)
+    end_rows = jnp.clip(jnp.asarray(end_rows, jnp.int32), 0, n)
+    has = end_rows > start_rows
+    first = jnp.min(jnp.where(has, start_rows // block_n, n_tiles - 1))
+    last = jnp.max(jnp.where(has, -(-end_rows // block_n), 0))
+    tiles = jnp.stack([first, jnp.maximum(last, first + 1)])
+    out = _mv_kernel(F, labels, W, jnp.asarray(b, jnp.float32), tiles,
+                     start_rows, end_rows, block_n=block_n,
                      interpret=interpret)
-    if with_overflow:
-        return out, requested > cap
-    return out
+    return out, (tiles[1] - tiles[0]) * block_n
 
 
 def band_reclassify(F_sorted, labels, w, b, start_row, end_row, *,

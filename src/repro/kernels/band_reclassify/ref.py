@@ -1,19 +1,19 @@
-"""Pure-jnp oracle for band_reclassify (dynamic-slice formulation)."""
+"""Pure-jnp oracles for the band_reclassify kernels."""
 import jax
 import jax.numpy as jnp
 
 
-def multiview_band_reclassify_ref(F, labels, W, b, start_blocks, widths, *,
-                                  cap: int, block_n: int):
-    """Multi-view oracle: the single-view dynamic-slice formulation applied
-    per view against the one shared table."""
+def multiview_band_reclassify_ref(F, labels, W, b, start_rows, end_rows):
+    """Multi-view oracle: every margin of the shared table from one product,
+    and view v relabelled on rows [start_rows[v], end_rows[v]) only."""
     k, n = labels.shape
-
-    def one(lab_v, w_v, b_v, sb_v, width_v):
-        return band_reclassify_ref(F, lab_v[:, None], w_v, b_v, sb_v, width_v,
-                                   cap=cap, block_n=block_n)[:, 0]
-
-    return jax.vmap(one)(labels, W, b, start_blocks, widths)
+    z = jnp.einsum("kd,nd->kn", W.astype(jnp.float32),
+                   F.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) - b[:, None]
+    new = jnp.where(z >= 0, 1, -1).astype(jnp.int8)
+    rows = jnp.arange(n)[None, :]
+    inside = (rows >= start_rows[:, None]) & (rows < end_rows[:, None])
+    return jnp.where(inside, new, labels)
 
 
 def band_reclassify_ref(F_sorted, labels, w, b, start_block, width, *,

@@ -215,7 +215,6 @@ class Catalog:
             facade = make_sharded_facade(t.features, k, p=opts.p, q=opts.q,
                                          lr=opts.lr, l2=opts.l2,
                                          alpha=opts.alpha,
-                                         cap_frac=opts.cap_frac,
                                          metrics=self.metrics)
         return self._register_view(ViewDef(name, table, model, facade, opts))
 

@@ -206,7 +206,7 @@ class ViewOptions(_OptionSchema):
     l2: float = 1e-4
     cost_mode: str = "measured"
     touch_ns: float = 0.0
-    cap_frac: float = 0.5
+    cap_frac: float = 0.5                   # accepted; read by no engine
     memory_budget: Optional[float] = None
     page_bytes: Optional[int] = None
     prefetch: bool = False

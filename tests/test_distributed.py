@@ -142,7 +142,7 @@ _MULTIVIEW_VS_HOST = """
         F = np.ascontiguousarray(c.features[:n]); d = F.shape[1]
         host = MultiViewEngine(F, k, p=2.0, q=2.0, cost_mode="modeled")
         sh = ShardedMultiViewHazy(mesh=mesh, n=n, d=d, k=k,
-                                  M=holder_M(F, 2.0), p=2.0, cap_frac=1/2)
+                                  M=holder_M(F, 2.0), p=2.0)
         state = sh.init_state(F)
         W = np.zeros((k, d), np.float32); b = np.zeros(k, np.float64)
         lr, l2 = 0.1, 1e-4
@@ -180,7 +180,7 @@ _MULTIVIEW_VS_HOST = """
         # must agree with the host labels for every sampled entity
         resolved_total = 0
         for i in range(0, n, 61):
-            lab, resolved = sh.hybrid_labels_of(state, W, b, int(i))
+            lab, resolved = sh.hybrid_labels_of(state, int(i))
             assert np.array_equal(lab, host_full[:, i]), (i, lab)
             resolved_total += int(resolved.sum())
         assert resolved_total > 0      # the waters tier did real work

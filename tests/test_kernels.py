@@ -60,76 +60,119 @@ def test_band_reclassify_sweep(n, d, start, end):
     assert np.array_equal(out, expect)
 
 
+def _multiview_oracle(F, labels, W, b, starts, ends):
+    """numpy: view v relabelled on rows [starts[v], ends[v]) from f64
+    margins, every other label as it was; also the mask of relabelled
+    (view, row) pairs whose margin is too near 0 for f32 to settle."""
+    z = np.asarray(W, np.float64) @ np.asarray(F, np.float64).T \
+        - np.asarray(b, np.float64)[:, None]
+    rows = np.arange(labels.shape[1])[None, :]
+    inside = (rows >= np.asarray(starts)[:, None]) & \
+             (rows < np.asarray(ends)[:, None])
+    expect = np.where(inside, np.where(z >= 0, 1, -1),
+                      np.asarray(labels)).astype(np.int8)
+    return expect, inside & (np.abs(z) < 1e-3)
+
+
+def _mv_case(k, n, d):
+    F = jnp.asarray(R.normal(size=(n, d)), jnp.float32)
+    labels = jnp.asarray(R.integers(0, 2, (k, n)) * 2 - 1, jnp.int8)
+    W = jnp.asarray(R.normal(size=(k, d)), jnp.float32)
+    b = jnp.asarray(R.normal(size=k), jnp.float32)
+    return F, labels, W, b
+
+
+def _check_mv(F, labels, W, b, starts, ends, block_n):
+    starts = np.asarray(starts, np.int32)
+    ends = np.asarray(ends, np.int32)
+    out, streamed = multiview_band_reclassify(F, labels, W, b, starts, ends,
+                                              block_n=block_n, interpret=True)
+    out = np.asarray(out)
+    expect, near = _multiview_oracle(F, labels, W, b, starts, ends)
+    assert np.array_equal(out[~near], expect[~near])
+    assert np.isin(out[near], (-1, 1)).all()
+    ref = multiview_band_reclassify_ref(F, labels, W, b, jnp.asarray(starts),
+                                        jnp.asarray(ends))
+    assert np.array_equal(out, np.asarray(ref))
+    return out, int(streamed)
+
+
 @pytest.mark.parametrize("k,n,d", [(4, 2048, 64), (7, 2048, 128), (16, 4096, 32)])
 def test_multiview_band_reclassify_sweep(k, n, d):
-    """Multi-view kernel == per-view dynamic-slice oracle on one shared
-    table, with independent per-view windows (incl. empty and clamped)."""
-    F = jnp.asarray(R.normal(size=(n, d)), jnp.float32)
-    labels = jnp.asarray(R.integers(0, 2, (k, n)) * 2 - 1, jnp.int8)
-    W = jnp.asarray(R.normal(size=(k, d)), jnp.float32)
-    b = jnp.asarray(R.normal(size=k), jnp.float32)
-    starts = jnp.asarray(R.integers(0, n, k), jnp.int32)
-    ends = jnp.minimum(starts + jnp.asarray(R.integers(0, 1500, k), jnp.int32), n)
-    cap, block_n = 2048, 256
-    out = multiview_band_reclassify(F, labels, W, b, starts, ends,
-                                    cap=cap, block_n=block_n, interpret=True)
-    start_blocks = jnp.clip(starts // block_n, 0, max(0, (n - cap) // block_n))
-    widths = jnp.clip(ends - start_blocks * block_n, 0, cap)
-    ref = multiview_band_reclassify_ref(F, labels, W, b, start_blocks, widths,
-                                        cap=cap, block_n=block_n)
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
-    # numpy cross-check: per view, window rows relabeled, others untouched
-    for v in range(k):
-        w0 = int(start_blocks[v]) * block_n
-        wd = int(widths[v])
-        expect = np.asarray(labels[v]).copy()
-        z = np.asarray(F[w0:w0 + wd]) @ np.asarray(W[v]) - float(b[v])
-        expect[w0:w0 + wd] = np.where(z >= 0, 1, -1)
-        assert np.array_equal(np.asarray(out[v]), expect), v
+    """Multi-view kernel == the numpy oracle and the jnp oracle on one
+    shared table, with independent per-view windows (some empty); the
+    kernel streams the union window rounded out to tiles."""
+    F, labels, W, b = _mv_case(k, n, d)
+    starts = R.integers(0, n, k)
+    ends = np.minimum(starts + R.integers(0, 1500, k), n)
+    ends[0] = starts[0]                               # one empty window
+    block_n = 256
+    _, streamed = _check_mv(F, labels, W, b, starts, ends, block_n)
+    has = ends > starts
+    first = starts[has].min() // block_n
+    last = -(-ends[has].max() // block_n)
+    assert streamed == (last - first) * block_n
 
 
-def test_multiview_band_reclassify_overflow_flag():
-    """A band wider than the kernel capacity is truncated — rows past the
-    capacity keep STALE labels — and the per-view overflow flag must say so
-    (the SKIING driver reorganizes on it instead of shipping those labels)."""
-    k, n, d, cap, block_n = 3, 2048, 32, 512, 256
-    F = jnp.asarray(R.normal(size=(n, d)), jnp.float32)
-    labels = jnp.asarray(R.integers(0, 2, (k, n)) * 2 - 1, jnp.int8)
-    W = jnp.asarray(R.normal(size=(k, d)), jnp.float32)
-    b = jnp.asarray(R.normal(size=k), jnp.float32)
-    # view 0: band wider than cap; view 1: exactly cap from an aligned
-    # start (no overflow); view 2: empty band
-    starts = jnp.asarray([256, 256, 0], jnp.int32)
-    ends = jnp.asarray([256 + cap + 1, 256 + cap, 0], jnp.int32)
-    out, overflow = multiview_band_reclassify(
-        F, labels, W, b, starts, ends, cap=cap, block_n=block_n,
-        interpret=True, with_overflow=True)
-    assert np.array_equal(np.asarray(overflow), [True, False, False])
-    # overflowed view: the cap-window rows WERE relabeled, the rest stale
-    z0 = np.asarray(F[256:256 + cap]) @ np.asarray(W[0]) - float(b[0])
-    expect0 = np.asarray(labels[0]).copy()
-    expect0[256:256 + cap] = np.where(z0 >= 0, 1, -1)
-    assert np.array_equal(np.asarray(out[0]), expect0)
-    assert np.array_equal(np.asarray(out[2]), np.asarray(labels[2]))
-    # default call keeps the legacy single-return signature
-    out2 = multiview_band_reclassify(F, labels, W, b, starts, ends,
-                                     cap=cap, block_n=block_n, interpret=True)
-    assert np.array_equal(np.asarray(out2), np.asarray(out))
+# (k, d) of the tests' shapes; windows as fractions of the table
+MV_SHAPES = [(4, 64), (7, 54), (16, 512)]
+MV_WINDOWS = {
+    # wider than half the table, one spanning it whole
+    "wide": lambda k: [(0.0, 1.0)] + [(0.1 * (v % 3), 0.55 + 0.1 * (v % 4))
+                                      for v in range(1, k)],
+    # one view empty while the others are wide
+    "one_empty": lambda k: [(0.5, 0.5)] + [(0.0, 0.6 + 0.1 * (v % 4))
+                                           for v in range(1, k)],
+    # every band empty: nothing relabelled
+    "all_empty": lambda k: [(0.0, 0.0)] * k,
+    # windows ending on the last tile, one of them inside it alone
+    "last_tile": lambda k: [(0.99, 1.0)] + [(0.3 + 0.05 * (v % 5), 1.0)
+                                            for v in range(1, k)],
+    # narrow windows off tile boundaries, away from the table's ends
+    "unaligned": lambda k: [(0.3 + 0.013 * v, 0.31 + 0.021 * v)
+                            for v in range(k)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(MV_WINDOWS))
+@pytest.mark.parametrize("k,d", MV_SHAPES)
+def test_multiview_band_reclassify_union_window(k, d, case):
+    """Union-window kernel == numpy oracle: each view relabels its own
+    window; every label outside it stays byte-identical; the kernel
+    streams the union window once (one tile where every band is empty)."""
+    n, block_n = 2048, 256
+    F, labels, W, b = _mv_case(k, n, d)
+    win = np.asarray(MV_WINDOWS[case](k)) * n
+    starts, ends = win[:, 0].astype(np.int32), win[:, 1].astype(np.int32)
+    out, streamed = _check_mv(F, labels, W, b, starts, ends, block_n)
+    rows = np.arange(n)[None, :]
+    outside = (rows < starts[:, None]) | (rows >= ends[:, None])
+    assert np.array_equal(out[outside], np.asarray(labels)[outside])
+    has = ends > starts
+    if case == "all_empty":
+        assert np.array_equal(out, np.asarray(labels))
+        assert streamed == block_n
+    else:
+        first = starts[has].min() // block_n
+        last = -(-ends[has].max() // block_n)
+        assert streamed == (last - first) * block_n
 
 
 def test_multiview_band_reclassify_matches_single_view():
-    """k=1 multi-view launch == the original single-view kernel."""
+    """k=1 multi-view launch == the original single-view kernel (from a
+    tile-aligned start: the single-view kernel relabels from the start of
+    the start's tile, the multi-view one from the start itself)."""
     n, d = 2048, 64
     F = jnp.asarray(np.sort(R.normal(size=(n, d)), axis=0), jnp.float32)
     labels = jnp.asarray(R.integers(0, 2, n) * 2 - 1, jnp.int8)
     w = jnp.asarray(R.normal(size=d), jnp.float32)
-    single = band_reclassify(F, labels, w, 0.1, 300, 900,
+    single = band_reclassify(F, labels, w, 0.1, 256, 900,
                              cap=1024, block_n=256, interpret=True)
-    multi = multiview_band_reclassify(F, labels[None, :], w[None, :],
-                                      jnp.asarray([0.1], jnp.float32),
-                                      jnp.asarray([300], jnp.int32),
-                                      jnp.asarray([900], jnp.int32),
-                                      cap=1024, block_n=256, interpret=True)
+    multi, _ = multiview_band_reclassify(F, labels[None, :], w[None, :],
+                                         jnp.asarray([0.1], jnp.float32),
+                                         jnp.asarray([256], jnp.int32),
+                                         jnp.asarray([900], jnp.int32),
+                                         block_n=256, interpret=True)
     assert np.array_equal(np.asarray(single), np.asarray(multi[0]))
 
 

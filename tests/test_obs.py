@@ -554,12 +554,49 @@ def test_round_and_read_span_counts_reconcile_with_driver_counters():
     assert count("round.update") == d.kernel_rounds
     assert count("round.sync") == d.kernel_rounds
     assert count("round.reorganize") == d.skiing.reorgs
-    assert count("read.margin") == f.disk_touches
+    assert count("read.margin") == 0        # the probe program takes the row
     assert count("read.probe") == 16 * 10
     assert count("round.sgd") == ex.log.commits == 10
     # a round either dispatches the band update or SKIING reorganizes it
     assert count("round.fetch") == count("round.waters") == d.kernel_rounds
     assert d.kernel_rounds + d.skiing.reorgs - d.overflows == 10
+
+
+def test_wide_band_rounds_relabel_without_reorganizing():
+    """Rounds whose bands cover more than half the table relabel through
+    the band kernel alone: no reorganize (SKIING's threshold is high), no
+    overflow, labels equal to a from-scratch relabel, and
+    `band.window_rows` takes one observation a launch, in SHOW METRICS."""
+    pytest.importorskip("jax")
+    from repro.data import multiclass_corpus
+    n, d, k = 1000, 16, 4
+    c = multiclass_corpus("wide", n, d, k, seed=2)
+    catalog = Catalog()
+    catalog.register_table("t", c.features, truth=c.classes, num_classes=k)
+    catalog.create_view("v", "t", "svm", {"engine": "sharded", "k": k,
+                                          "p": 2, "q": 2, "lr": 0.5,
+                                          "alpha": 1000.0})
+    ex = Executor(catalog, group_commit=8)
+    f = catalog.view("v").facade
+    drv = f.driver
+    rng = np.random.default_rng(2)
+    for _ in range(4):
+        ids = rng.integers(0, n, 8)
+        ex.execute_one("INSERT INTO t (id, class) VALUES " + ", ".join(
+            f"({int(i)}, {int(c.classes[i])})" for i in ids))
+    # every round went to the kernel, and its bands covered over half
+    assert drv.kernel_rounds == 4 and drv.skiing.reorgs == 0
+    assert drv.overflows == 0
+    assert drv.skiing.total_incremental / drv.kernel_rounds > 0.5
+    gids, labels, _ = drv.real_rows(f.state)
+    z = f.F[gids] @ f.W.T - f.b.astype(np.float32)
+    settled = np.abs(z) > 1e-4
+    assert np.array_equal(labels.T[settled], np.where(z >= 0, 1, -1)[settled])
+    hist = ex.metrics_snapshot()["histograms"]["band.window_rows"]
+    assert hist["count"] == drv.kernel_rounds
+    assert drv.n_pad / 2 < hist["sum"] / hist["count"] <= drv.n_pad
+    flat = dict(ex.execute_one("SHOW METRICS").rows)
+    assert flat["histograms.band.window_rows.count"] == drv.kernel_rounds
 
 
 def test_profiler_trace_holds_nested_statement_and_round_spans(tmp_path):
@@ -580,7 +617,7 @@ def test_profiler_trace_holds_nested_statement_and_round_spans(tmp_path):
               "round.sgd": "wal.commit", "round.fetch": "wal.commit",
               "round.waters": "wal.commit", "round.update": "wal.commit",
               "round.sync": "wal.commit", "round.reorganize": "wal.commit",
-              "read.probe": "probe", "read.margin": "probe"}
+              "read.probe": "probe"}
     seen = {}
     for plane in data.planes:
         for line in plane.lines:
@@ -624,17 +661,17 @@ def test_programs_carry_their_names_in_compile_events():
         reg = MetricsRegistry()
         drv = sharded.ShardedMultiViewHazy(
             mesh=make_host_mesh((1, 1)), n=n, d=d, k=k, M=holder_M(F, 2.0),
-            cap_frac=0.5, metrics=reg)
+            metrics=reg)
         state = drv.init_state(F)
         W = rng.normal(size=(k, d)).astype(np.float32) * 0.01
         b = np.zeros(k)
         state = drv.apply_models(state, W, b)
         drv.lw[:], drv.hw[:] = -1e9, 1e9      # every view misses
-        drv.hybrid_labels_of(state, W, b, 3)
+        drv.hybrid_labels_of(state, 3)
         drv.all_members(state)
     finally:
         jax.monitoring.unregister_event_duration_listener(listen)
-    want = {"band_update", "reorganize", "probe", "margin", "all_members"}
+    want = {"band_update", "reorganize", "probe", "all_members"}
     assert {f"jit({p})" for p in want} <= set(names)
     counters = reg.snapshot()["counters"]
     assert all(counters[f"compiles.{p}"] >= 1 for p in want)

@@ -330,7 +330,7 @@ def test_sql_equals_direct_sharded():
     ex = Executor(catalog, group_commit=GROUP)
 
     driver = ShardedMultiViewHazy(mesh=make_host_mesh((1, 1)), n=n, d=d, k=k,
-                                  M=holder_M(F, 2.0), p=2.0, cap_frac=0.5)
+                                  M=holder_M(F, 2.0), p=2.0)
     state = driver.init_state(F)
     W = np.zeros((k, d), np.float32)
     b = np.zeros(k, np.float64)
@@ -367,7 +367,7 @@ def test_sql_equals_direct_sharded():
         got = [r[2] for r in ex.execute_one(
             f"SELECT id, view, label FROM v WHERE id = {i}").rows]
         flush()
-        want, _ = driver.hybrid_labels_of(state, W, b, i)
+        want, _ = driver.hybrid_labels_of(state, i)
         assert np.array_equal(got, want), i
 
     ex.execute_one("COMMIT")
