@@ -74,6 +74,12 @@ def module_from(path: Path):
     return mod
 
 
+def bench_dir(root: Path = ROOT) -> Path:
+    """This directory's place in a checkout rooted at `root`: where the
+    mix and the limits of a cell are found."""
+    return root / HERE.relative_to(ROOT)
+
+
 def load_cell(name: str, root: Path = ROOT):
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -83,9 +89,10 @@ def load_cell(name: str, root: Path = ROOT):
     cell = cells[name]
     entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     cfg = json.loads((root / entry["file"]).read_text())
-    traffic = json.loads((HERE / "traffic" / f"{cell['traffic']}.json")
+    bench = bench_dir(root)
+    traffic = json.loads((bench / "traffic" / f"{cell['traffic']}.json")
                          .read_text())
-    limits = json.loads((HERE / "limits" / f"{cell['config']}.json")
+    limits = json.loads((bench / "limits" / f"{cell['config']}.json")
                         .read_text())
     return manifest, cell, cfg, traffic, limits
 
@@ -133,8 +140,9 @@ class Run:
     (`t0`, `t1`, `seconds`), `window_commits`, `window_reads`, `wal_delta`
     (count, sum of `span.wal.commit.seconds`), `counter_delta` (kernel
     rounds, overflows, reorganizes), `tier_delta`, `trace` (the reduced
-    trace, traced runs only) and `launch_need_s` (per band-kernel launch
-    in the window, the seconds its need takes at the peak bandwidth)."""
+    trace, traced runs only), `launch_need_s` (per band-kernel round in
+    the window, the seconds its need takes at one chip's peak bandwidth)
+    and `cell` (its `chips`)."""
 
     def __init__(self, cell, cfg, traffic, seed: int, seconds: float,
                  trace: bool, peaks: Optional[dict], rows: Optional[int],
@@ -328,9 +336,10 @@ class Run:
 
     # -- after the window -------------------------------------------------
     def memory_peak(self) -> int:
+        """The peak bytes in use on the fullest of the cell's chips."""
         import jax
-        stats = jax.devices()[0].memory_stats() or {}
-        return int(stats.get("peak_bytes_in_use", 0))
+        return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                   for d in jax.devices()[:int(self.cell["chips"])])
 
     def check(self):
         """The numbers compared with their limits, from the reference."""
@@ -533,8 +542,11 @@ class Run:
 
 
 def run_cell(argv: Optional[Sequence[str]] = None, *,
-             rehearsal: Optional[dict] = None, root: Path = ROOT, out=sys.stdout, err=sys.stderr) -> int:
-    """One run. `rehearsal` (tests only) runs on the CPU at a tiny size:
+             rehearsal: Optional[dict] = None, root: Path = ROOT,
+             out=sys.stdout, err=sys.stderr) -> int:
+    """One run. The cell, its configuration, mix and limits are read from
+    the checkout at `root`. `rehearsal` (tests only; a configuration's
+    `rehearsal` object) runs on the CPU at a tiny size:
     {"rows": ..., "warm_scale": ..., "table": {key: value, ...}}."""
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)

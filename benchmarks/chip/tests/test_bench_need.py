@@ -1,9 +1,14 @@
-"""The band kernel's need count against a brute-force count."""
+"""The band kernel's need count against a brute-force count, and its
+roofline share on one chip and on four."""
+from types import SimpleNamespace
+
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 import need
 import reference
+import run
 
 
 def test_union_band_rows_matches_brute_force():
@@ -33,3 +38,20 @@ def test_need_bytes_and_seconds():
     peaks = {"hbm_bytes_per_s": 819e9}
     assert np.isclose(need.need_seconds(1000, 1024, 16, peaks),
                       1000 * 4128 / 819e9)
+
+
+@pytest.mark.parametrize("chips,want", [
+    # rounds needing 8 and 4 ms of one chip's HBM time, launches of 12 ms
+    # on average (36 ms over 3): 6 / 12 = 50%
+    (1, 50.0),
+    # the same rounds on four chips that share the table: each chip's
+    # share of a round needs 1.5 ms against its 12 ms launch
+    (4, 12.5),
+])
+def test_band_kernel_roofline(chips, want):
+    reader = run.module_from(run.HERE / "layers" / "band_kernel_roofline.py")
+    stub = SimpleNamespace(cell={"chips": chips}, launch_need_s=[8e-3, 4e-3],
+                           trace={"kernel_s": 36e-3, "kernel_launches": 3})
+    assert reader.read(stub) == pytest.approx(want)
+    assert reader.read(SimpleNamespace(cell={"chips": chips}, trace=None,
+                                       launch_need_s=[8e-3])) is None

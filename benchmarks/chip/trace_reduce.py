@@ -5,13 +5,18 @@ directory with JAX's own reader and keeps two event lists, each of
 (name, start_ns, duration_ns):
 
   * device: the operations on the accelerator's op line ("XLA Ops" of each
-    `/device:TPU:N` plane); on a CPU rehearsal, the XLA CPU worker threads;
+    `/device:TPU:N` plane); on a CPU rehearsal, the XLA CPU worker threads.
+    Each event has a fourth element, the index of its plane in
+    `device_planes`; an event without one is on plane 0;
   * host: the benchmark's own `TraceAnnotation`s ("commit", "read").
 
-`reduce(...)` turns them into the device's busy time (the union of the op
-intervals), a kernel's time and launch count, the device operations that
-took most time, and the idle gaps, each attributed to the host annotation
-that covers most of it.
+`reduce(...)` turns them into readings of one chip, averaged over the
+planes: the busy time (on each plane the union of its op intervals), a
+kernel's time and launch count (summed over the planes, so time over
+launches is one chip's launch), the device operations that took most
+time, and the idle gaps of each plane, each attributed to the host
+annotation that covers most of it. With one plane the chip's readings are
+the trace's.
 
 The kept lists are plain JSON (`load_events` reads them gzipped), which is
 how the tests hold a small recorded trace.
@@ -60,11 +65,14 @@ def load(logdir: str) -> Dict[str, list]:
             for ev in line.events:
                 if dev:
                     device.append((op_name(ev.name), int(ev.start_ns),
-                                   int(ev.duration_ns)))
+                                   int(ev.duration_ns), plane.name))
                 elif ev.name in HOST_NAMES:
                     host.append((ev.name, int(ev.start_ns),
                                  int(ev.duration_ns)))
-    return {"device": device, "host": host, "device_planes": sorted(planes)}
+    planes = sorted(planes)
+    index = {p: i for i, p in enumerate(planes)}
+    device = [(n, s, d, index[p]) for n, s, d, p in device]
+    return {"device": device, "host": host, "device_planes": planes}
 
 
 def load_events(path: str) -> Dict[str, list]:
@@ -90,8 +98,11 @@ def reduce(events: Dict[str, list], kernel: str,
     """busy_s, window_s, kernel_s, kernel_launches, device_ops, idle_gaps.
 
     window_ns: (start, end) of the traced window on the trace's clock;
-    by default from the first op's start to the last op's end. The device
-    count (`n_devices`) averages busy time over the chips traced."""
+    by default from the first op's start to the last op's end on any
+    plane. Over the planes (`device_planes`, at least those the events
+    name): busy_s, device_ops and the "(all gaps)" seconds are means,
+    kernel_s and kernel_launches sums, a "(longest gap)" the longest on
+    any plane; a plane with no op is idle for the whole window."""
     dev = events["device"]
     if not dev:
         return {"busy_s": 0.0, "window_s": 0.0, "kernel_s": 0.0,
@@ -99,29 +110,38 @@ def reduce(events: Dict[str, list], kernel: str,
     names = [e[0] for e in dev]
     start = np.array([e[1] for e in dev], np.int64)
     dur = np.array([e[2] for e in dev], np.int64)
+    plane = np.array([e[3] if len(e) > 3 else 0 for e in dev], np.int64)
     end = start + dur
     lo, hi = window_ns if window_ns else (int(start.min()), int(end.max()))
     start, end = np.clip(start, lo, hi), np.clip(end, lo, hi)
-    n_dev = max(1, len(events.get("device_planes", [])))
-    us, ue = _union(start, end)
-    busy_ns = int((ue - us).sum())
+    n_dev = max(1, len(events.get("device_planes", [])),
+                int(plane.max()) + 1)
+    busy_ns = 0
+    gap_s, gap_e = [], []
+    for p in range(n_dev):
+        on = plane == p
+        us, ue = _union(start[on], end[on])
+        busy_ns += int((ue - us).sum())
+        # idle gaps between this plane's merged busy intervals, inside the
+        # window
+        gs, ge = np.append(lo, ue), np.append(us, hi)
+        keep = ge > gs
+        gap_s.append(gs[keep])
+        gap_e.append(ge[keep])
     pat = re.compile(kernel)
     is_k = np.array([bool(pat.search(n)) for n in names])
     per_op = defaultdict(int)
     for n, d in zip(names, (end - start).tolist()):
         per_op[n] += d
     ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:TOP]
-    # idle gaps between merged busy intervals, inside the window
-    gs = np.append(lo, ue)
-    ge = np.append(us, hi)
-    keep = ge > gs
-    gaps = _attribute(gs[keep], ge[keep], events.get("host", []))
+    gaps = _attribute(np.concatenate(gap_s), np.concatenate(gap_e),
+                      events.get("host", []), n_dev)
     return {
         "busy_s": busy_ns / n_dev / 1e9,
         "window_s": (hi - lo) / 1e9,
         "kernel_s": float((end - start)[is_k].sum()) / 1e9,
         "kernel_launches": int(is_k.sum()),
-        "device_ops": [[n, d / 1e9] for n, d in ops],
+        "device_ops": [[n, d / n_dev / 1e9] for n, d in ops],
         "idle_gaps": gaps,
     }
 
@@ -134,10 +154,12 @@ def _covered(s: np.ndarray, e: np.ndarray, t: np.ndarray) -> np.ndarray:
     return np.where(i >= 0, cum[np.maximum(i, 0)] + part, 0)
 
 
-def _attribute(gs: np.ndarray, ge: np.ndarray, host: List[list]):
-    """Total idle seconds by what the host was doing (the annotation that
-    overlaps each gap most, else "none"), and the longest single gap of
-    each kind, largest first."""
+def _attribute(gs: np.ndarray, ge: np.ndarray, host: List[list],
+               n_dev: int = 1):
+    """Idle seconds by what the host was doing (the annotation that
+    overlaps each gap most, else "none"): the total over `n_dev` planes'
+    gaps divided by `n_dev`, and the longest single gap of each kind,
+    largest first."""
     names = sorted({h[0] for h in host})
     overlap = np.zeros((len(names) + 1, gs.size), np.int64)
     for j, n in enumerate(names, start=1):
@@ -151,6 +173,7 @@ def _attribute(gs: np.ndarray, ge: np.ndarray, host: List[list]):
     rows = []
     for j in np.unique(best):
         sel = length[best == j]
-        rows.append([f"{labels[j]} (all gaps)", float(sel.sum()) / 1e9])
+        rows.append([f"{labels[j]} (all gaps)",
+                     float(sel.sum()) / n_dev / 1e9])
         rows.append([f"{labels[j]} (longest gap)", float(sel.max()) / 1e9])
     return sorted(rows, key=lambda r: -r[1])[:TOP]
