@@ -550,6 +550,11 @@ class ShardedFacade(EngineFacade):
         self.state = driver.init_state(self.F)
         self._disk = 0
 
+    def telemetry_snapshot(self) -> dict:
+        out = super().telemetry_snapshot()
+        out["shards"] = int(self.driver.shards)   # row shards of the mesh
+        return out
+
     def insert_examples(self, ids, labels):
         # `round.sgd`: the stacked SGD on the host, before the driver's
         # round.* spans (`ShardedMultiViewHazy.apply_models`)
@@ -639,17 +644,14 @@ class ShardedFacade(EngineFacade):
                 self.F[ids] @ self.W[v] - self.b[v], np.float64))
 
 
-def make_sharded_facade(features: np.ndarray, k: int, *, p: float = 2.0,
-                        q: float = 2.0, lr: float = 0.1, l2: float = 1e-4,
-                        alpha: float = 1.0, mesh=None,
+def make_sharded_facade(features: np.ndarray, k: int, *, mesh,
+                        p: float = 2.0, q: float = 2.0, lr: float = 0.1,
+                        l2: float = 1e-4, alpha: float = 1.0,
                         metrics=None) -> ShardedFacade:
-    """Build a `ShardedFacade` on `mesh` (default: single-host (1, 1)); its
-    spans and compile counts go to the registry `metrics`."""
+    """Build a `ShardedFacade` on `mesh` (the catalog's (shards, 1) row
+    mesh); its spans and compile counts go to the registry `metrics`."""
     from repro.core.sharded import ShardedMultiViewHazy
     from repro.core.waters import holder_M
-    if mesh is None:
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh((1, 1))
     F = np.ascontiguousarray(features, np.float32)
     driver = ShardedMultiViewHazy(
         mesh=mesh, n=F.shape[0], d=F.shape[1], k=int(k),

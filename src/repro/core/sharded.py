@@ -112,6 +112,11 @@ def _row_axes(mesh: Mesh):
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
+def row_shards(mesh: Mesh) -> int:
+    """How many row shards the table is cut into on `mesh`."""
+    return int(np.prod([mesh.shape[a] for a in _row_axes(mesh)]))
+
+
 def _specs(mesh: Mesh):
     rows = _row_axes(mesh)
     model = "model" if "model" in mesh.axis_names else None
@@ -199,8 +204,7 @@ def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
     pf, pr, pw = _specs(mesh)
     model_ax = "model" if "model" in mesh.axis_names else None
     rows = _row_axes(mesh)
-    n_shards = int(np.prod([mesh.shape[a] for a in rows])) if rows else 1
-    n_local = n // n_shards
+    n_local = n // row_shards(mesh)
     cap = max(64, int(n_local * cap_frac))
 
     def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
@@ -441,8 +445,7 @@ def mv_tiles(mesh: Mesh, n: int, d: int):
     row shard holds n_local rows: its share of the n real rows padded up to
     a multiple of block_n, so the table holds n_pad = n_local * shards rows
     (the padding sits at the end)."""
-    rows = _row_axes(mesh)
-    n_shards = int(np.prod([mesh.shape[a] for a in rows])) if rows else 1
+    n_shards = row_shards(mesh)
     per_shard = -(-n // n_shards)
     block_n = VMEM_TILE_BYTES // (4 * _round_up(d, 128)) // 128 * 128
     block_n = min(max(128, block_n), _round_up(per_shard, 128))
@@ -467,11 +470,13 @@ def make_multiview_update_step(mesh: Mesh, block_n: int):
     window of the Lemma 3.1 band in the shared order (pure device compute,
     no per-view dynamic slices), then `multiview_band_reclassify` streams
     the union of the k windows HBM->VMEM once and relabels each view's
-    window under the stacked models. Returns (labels', counts (k + 1,)
-    i32): the true band width of each view, then the rows the kernel
-    streamed, each summed over shards — one array, so one host copy."""
+    window under the stacked models. Returns (labels', counts (k + shards,)
+    i32): the true band width of each view summed over shards, then the
+    rows each shard's kernel streamed, in shard order — one array from one
+    psum, so one collective and one host copy."""
     pf, pr, pkr = _mv_specs(mesh)
     rows = _row_axes(mesh)
+    shards = row_shards(mesh)
     interpret = kernel_interpret(mesh)
 
     def local(F, gids, eps, labels, W_s, b_s, lw, hw, W, b):
@@ -479,7 +484,12 @@ def make_multiview_update_step(mesh: Mesh, block_n: int):
         labels, streamed = multiview_band_reclassify(
             F, labels, W, b, start, end, block_n=block_n,
             interpret=interpret)
-        counts = jnp.concatenate([width, streamed[None].astype(jnp.int32)])
+        shard = 0                 # this shard's place in the row order
+        for ax in rows:
+            shard = shard * mesh.shape[ax] + jax.lax.axis_index(ax)
+        mine = jnp.zeros(shards, jnp.int32).at[shard].set(
+            streamed.astype(jnp.int32))
+        counts = jnp.concatenate([width, mine])
         for ax in rows:
             counts = jax.lax.psum(counts, ax)
         return labels, counts
@@ -603,9 +613,13 @@ class ShardedMultiViewHazy:
     `multiview_band_reclassify` kernel against the device-resident shared
     clustering order; SKIING alone decides when to reorganize. `n` counts
     real entity rows; the device table holds `n_pad` rows, `cap` of them
-    on each shard — the widest window the kernel takes. In its metrics
-    registry the histogram `band.window_rows` takes, once a launch, the
-    rows the kernel streamed (summed over shards)."""
+    on each of its `shards` row shards — the widest window the kernel
+    takes. What a round sends to the device (models, waters, a read's
+    entity id) is put replicated on the mesh. In its metrics registry,
+    once a launch, the histogram `band.window_rows` takes the rows the
+    kernel streamed summed over shards, and `band.shard_window_rows_max`
+    the widest shard's; a launch inside a profiled window also feeds
+    their `profiled.` twins."""
     mesh: Mesh
     n: int
     d: int
@@ -617,8 +631,10 @@ class ShardedMultiViewHazy:
 
     def __post_init__(self):
         observe(self.metrics)
+        self.shards = row_shards(self.mesh)
         self.n_pad, self.block_n, self.cap = mv_tiles(
             self.mesh, self.n, self.d)
+        self._replicated = NamedSharding(self.mesh, P())
         self._update = jax.jit(
             make_multiview_update_step(self.mesh, self.block_n))
         self._reorg = jax.jit(make_multiview_reorganize_step(self.mesh))
@@ -628,27 +644,46 @@ class ShardedMultiViewHazy:
         self.skiing = Skiing(S=1.0, alpha=self.alpha)
         self.lw = np.zeros(self.k, np.float64)
         self.hw = np.zeros(self.k, np.float64)
-        # the last round's (W, b) on the device: point reads label under
-        # them without copying the models to the device again
-        self.models = (jnp.zeros((self.k, self.d), jnp.float32),
-                       jnp.zeros(self.k, jnp.float32))
+        # the last round's (W, b) on the mesh (from `init_state` on): point
+        # reads label under them without copying the models again
+        self.models = None
         self.kernel_rounds = 0    # update-step launches of the band kernel
         self.overflows = 0        # always 0: no kernel capacity to overflow
-        self._window_rows = (None if self.metrics is None else
-                             self.metrics.histogram("band.window_rows",
-                                                    WINDOW_ROW_BUCKETS))
 
-    def init_state(self, F: np.ndarray) -> ShardedMultiViewState:
+    def _replicate(self, x, dtype):
+        """A host value on every device of the mesh."""
+        return jax.device_put(np.asarray(x, dtype), self._replicated)
+
+    def _put_models(self, W, b):
+        return (self._replicate(W, np.float32), self._replicate(b, np.float32))
+
+    def put_table(self, F: np.ndarray) -> ShardedMultiViewState:
+        """The state before its first reorganize: each row shard of F is
+        put from the host table on its own device, and only the last
+        shard's padding rows are built on the host, so no padded copy of
+        the whole table is made. Span: `view.put_table`, which waits for
+        the table to reach the devices."""
         k, n, n_pad = self.k, self.n, self.n_pad
         specs = multiview_state_specs(n_pad, self.d, k, self.mesh)
         put = lambda x, s: jax.device_put(x, s.sharding)
         F = np.asarray(F, np.float32)
-        if n_pad > n:
-            F = np.concatenate([F, np.zeros((n_pad - n, self.d), np.float32)])
+
+        def rows(index):          # the rows of one shard, zeros past n
+            lo, hi, _ = index[0].indices(n_pad)
+            if hi <= n:
+                return F[lo:hi]
+            out = np.zeros((hi - lo, self.d), np.float32)
+            out[:max(0, n - lo)] = F[lo:n]
+            return out
+
         gids = np.full(n_pad, PAD_GID, np.int32)
         gids[:n] = np.arange(n, dtype=np.int32)
-        state = ShardedMultiViewState(
-            F=put(F, specs.F),
+        with trace.span("view.put_table", metrics=self.metrics):
+            F_dev = jax.make_array_from_callback(
+                (n_pad, self.d), specs.F.sharding, rows)
+            F_dev.block_until_ready()
+        return ShardedMultiViewState(
+            F=F_dev,
             gids=put(gids, specs.gids),
             eps=put(np.zeros((k, n_pad), np.float32), specs.eps),
             labels=put(np.zeros((k, n_pad), np.int8), specs.labels),
@@ -657,18 +692,37 @@ class ShardedMultiViewHazy:
             lw=put(np.zeros(k, np.float32), specs.lw),
             hw=put(np.zeros(k, np.float32), specs.hw),
         )
-        return self._reorg(state, jnp.zeros((k, self.d), jnp.float32),
-                           jnp.zeros(k, jnp.float32))
+
+    def init_state(self, F: np.ndarray) -> ShardedMultiViewState:
+        self.models = self._put_models(np.zeros((self.k, self.d)),
+                                       np.zeros(self.k))
+        return self._reorg(self.put_table(F), *self.models)
 
     def _do_reorg(self, state, W, b):
         with trace.span("round.reorganize", metrics=self.metrics):
-            self.models = (jnp.asarray(W, jnp.float32),
-                           jnp.asarray(b, jnp.float32))
+            self.models = self._put_models(W, b)
             state = self._reorg(state, *self.models)
         self.skiing.record_reorg()
         self.lw[:] = 0.0
         self.hw[:] = 0.0
         return state
+
+    def _observe_window(self, shard_rows: np.ndarray) -> None:
+        """One launch's streamed rows (one entry a shard) into
+        `band.window_rows` (their sum) and `band.shard_window_rows_max`
+        (the widest shard's), and into their `profiled.` twins while a
+        profile is collected."""
+        m = self.metrics
+        if m is None:
+            return
+        seen = {"band.window_rows": float(np.sum(shard_rows)),
+                "band.shard_window_rows_max": float(np.max(shard_rows))}
+        profiled = jax.profiler.TraceAnnotation.is_enabled()
+        for name, rows in seen.items():
+            m.histogram(name, WINDOW_ROW_BUCKETS).observe(rows)
+            if profiled:
+                m.histogram(f"profiled.{name}",
+                            WINDOW_ROW_BUCKETS).observe(rows)
 
     def apply_models(self, state: ShardedMultiViewState, W, b):
         """One eager round for all k views (modeled costs ∝ rows touched).
@@ -676,9 +730,10 @@ class ShardedMultiViewHazy:
         Its spans, children of the caller's (`wal.commit` when served):
         `round.fetch` pulls the stored model to the host (and so waits for
         the device), `round.waters` is the Eq. 2 arithmetic, `round.update`
-        dispatches the band update, `round.sync` blocks on its band widths
-        and streamed rows, and `round.reorganize` dispatches a reorganize
-        that SKIING called. `round.update` counts `kernel_rounds` and
+        puts the models and waters on the mesh and dispatches the band
+        update, `round.sync` blocks on its band widths and each shard's
+        streamed rows, and `round.reorganize` dispatches a reorganize that
+        SKIING called. `round.update` counts `kernel_rounds` and
         `round.reorganize` counts `skiing.reorgs`."""
         m = self.metrics
         if self.skiing.should_reorganize():
@@ -691,19 +746,17 @@ class ShardedMultiViewHazy:
                 self.lw, self.hw, np.asarray(W, np.float32),
                 np.asarray(b, np.float64), W_s, b_s, self.M, self.p)
         with trace.span("round.update", metrics=m):
-            self.models = (jnp.asarray(W, jnp.float32),
-                           jnp.asarray(b, jnp.float32))
-            state = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
-                                   hw=jnp.asarray(self.hw, jnp.float32))
+            self.models = self._put_models(W, b)
+            state = state._replace(lw=self._replicate(self.lw, np.float32),
+                                   hw=self._replicate(self.hw, np.float32))
             labels, counts = self._update(state, *self.models)
             state = state._replace(labels=labels)
             self.kernel_rounds += 1
         with trace.span("round.sync", metrics=m):
             counts = np.asarray(counts)
-        if self._window_rows is not None:
-            self._window_rows.observe(int(counts[-1]))
+        self._observe_window(counts[self.k:])
         self.skiing.record_incremental(
-            float(np.sum(counts[:-1])) / (self.n * self.k))
+            float(np.sum(counts[:self.k])) / (self.n * self.k))
         return state
 
     def all_members(self, state) -> np.ndarray:
@@ -728,8 +781,8 @@ class ShardedMultiViewHazy:
         Span: `read.probe` (the program's dispatch and its one host
         copy)."""
         with trace.span("read.probe", metrics=self.metrics):
-            st = state._replace(lw=jnp.asarray(self.lw, jnp.float32),
-                                hw=jnp.asarray(self.hw, jnp.float32))
-            out = np.asarray(self._probe(st, *self.models,
-                                         jnp.int32(entity_id)))
+            st = state._replace(lw=self._replicate(self.lw, np.float32),
+                                hw=self._replicate(self.hw, np.float32))
+            out = np.asarray(self._probe(
+                st, *self.models, self._replicate(entity_id, np.int32)))
         return out[0], out[1].astype(bool)

@@ -31,6 +31,16 @@ def make_elastic_mesh(num_devices: int, *, model_parallel: int = 16):
     return Mesh(arr, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
+def make_row_mesh(shards: int, axes=("data", "model")):
+    """A (shards, 1) mesh over the first `shards` devices: a table's rows
+    cut over `data`, whole rows on each device. With one shard it is
+    `make_host_mesh()`'s mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+    devices = np.array(jax.devices()[:shards]).reshape(shards, 1)
+    return Mesh(devices, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_host_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many (possibly fake) local devices exist —
     used by tests and CPU examples."""

@@ -10,7 +10,8 @@ builds one of the three engine shells behind an `EngineFacade` —
   engine=multiview  k one-vs-all views over ONE `MultiViewEngine` (default
                     whenever k > 1)
   engine=sharded    `ShardedMultiViewHazy` (device-resident shared order,
-                    Pallas band kernel; eager only)
+                    Pallas band kernel; eager only), its rows cut over
+                    the first `shards` devices (default 1)
 
 `CREATE CLASSIFICATION VIEW child ON parent` where `parent` is itself a
 view registers a *derived* view: its feature table is the parent's margin
@@ -52,6 +53,18 @@ from repro.rdbms.options import DOWNSTREAM, TableOptions, ViewOptions
 from repro.scheduler.state import ViewRuntime
 
 __all__ = ["BaseTable", "Catalog", "PlanError", "SqlError", "ViewDef"]
+
+
+def _row_mesh(shards: int):
+    """The (shards, 1) row mesh of an engine=sharded view, over the first
+    `shards` devices JAX sees."""
+    import jax
+    from repro.launch.mesh import make_row_mesh
+    have = len(jax.devices())
+    if shards > have:
+        raise PlanError(f"option shards = {shards} exceeds the {have} "
+                        f"device(s) JAX sees")
+    return make_row_mesh(shards)
 
 
 @dataclasses.dataclass
@@ -184,6 +197,13 @@ class Catalog:
             raise PlanError("prefetch = on requires memory_budget (the "
                             "readahead worker feeds a buffer pool)")
 
+        if opts.shards < 1:
+            raise PlanError(f"option shards must be at least 1, got "
+                            f"{opts.shards}")
+        if opts.shards != 1 and engine != "sharded":
+            raise PlanError(f"option shards = {opts.shards} requires "
+                            f"engine=sharded (only the device engine cuts "
+                            f"the table into row shards)")
         if model == "logistic" and engine != "hazy":
             # MulticlassView/ShardedFacade train hinge SVM only; a view
             # silently trained with the wrong loss is worse than an error
@@ -212,9 +232,10 @@ class Catalog:
             if opts.policy != "eager":
                 raise PlanError("engine=sharded maintains eagerly; "
                                 "policy must be eager")
-            facade = make_sharded_facade(t.features, k, p=opts.p, q=opts.q,
-                                         lr=opts.lr, l2=opts.l2,
-                                         alpha=opts.alpha,
+            facade = make_sharded_facade(t.features, k,
+                                         mesh=_row_mesh(opts.shards),
+                                         p=opts.p, q=opts.q, lr=opts.lr,
+                                         l2=opts.l2, alpha=opts.alpha,
                                          metrics=self.metrics)
         return self._register_view(ViewDef(name, table, model, facade, opts))
 
@@ -231,9 +252,9 @@ class Catalog:
         if opts.k not in (None, 1):
             raise PlanError("derived views are single-view (k = 1): their "
                             "input is the parent's one margin column")
-        if opts.engine not in (None, "hazy"):
+        if opts.engine not in (None, "hazy") or opts.shards != 1:
             raise PlanError("derived views require engine=hazy (k = 1 over "
-                            "the parent's margin column)")
+                            "the parent's margin column) and one shard")
         if (opts.memory_budget is not None or opts.page_bytes is not None
                 or opts.prefetch):
             raise PlanError("derived views keep their (n, 1) margin column "

@@ -184,6 +184,7 @@ _VIEW_SPECS = [
     OptionSpec("cost_mode", "choice", "measured", ("measured", "modeled")),
     OptionSpec("touch_ns", "float", 0.0),
     OptionSpec("cap_frac", "float", 0.5),
+    OptionSpec("shards", "int", 1),
     OptionSpec("memory_budget", "budget", None),
     OptionSpec("page_bytes", "int", None),
     OptionSpec("prefetch", "flag", False),
@@ -207,6 +208,7 @@ class ViewOptions(_OptionSchema):
     cost_mode: str = "measured"
     touch_ns: float = 0.0
     cap_frac: float = 0.5                   # accepted; read by no engine
+    shards: int = 1                         # row shards (engine=sharded)
     memory_budget: Optional[float] = None
     page_bytes: Optional[int] = None
     prefetch: bool = False
