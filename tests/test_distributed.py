@@ -245,6 +245,75 @@ def test_reorganize_step_has_no_cross_row_collectives():
     assert "NO_CROSS_ROW_COLLECTIVES" in out
 
 
+_REORGANIZE_VS_NUMPY = """
+    import numpy as np
+    from repro.core.engine import classify
+    from repro.core.sharded import PAD_GID, ShardedMultiViewHazy
+    from repro.launch.mesh import make_row_mesh
+    shards, n, d, k = SHARDS, 1000, 64, 5
+    mesh = make_row_mesh(shards)
+    sh = ShardedMultiViewHazy(mesh=mesh, n=n, d=d, k=k, M=1.0)
+    n_local = sh.n_pad // shards
+    assert sh.n_pad > n                  # padding rows on the last shard
+    r = np.random.default_rng(5)
+    # dyadic values: every margin is exact in f32 in any summation order,
+    # so the device's margins equal numpy's bit for bit, ties included
+    F = (r.integers(-8, 9, (n, d)) / 4).astype(np.float32)
+
+    def reference(F, gids, W, b):
+        Z = W @ F.T - b[:, None]
+        Z = np.where(gids[None, :] == PAD_GID, np.inf, Z).astype(np.float32)
+        order = np.concatenate([
+            lo + np.argsort(np.abs(Z[:, lo:lo + n_local]).min(axis=0),
+                            kind="stable")
+            for lo in range(0, sh.n_pad, n_local)])
+        gids_new = np.take(gids, order)
+        eps_new = np.take(Z, order, axis=1)
+        labels_new = np.where(gids_new[None, :] == PAD_GID, np.int8(0),
+                              classify(eps_new))
+        return np.take(F, order, axis=0), gids_new, eps_new, labels_new
+
+    state = sh.put_table(F)
+    F_ref = np.asarray(state.F)
+    gids_ref = np.asarray(state.gids)
+    for step in range(2):                # the second reorganize permutes
+        W = (r.integers(-4, 5, (k, d)) / 8).astype(np.float32)
+        b = (r.integers(-8, 9, k) / 2).astype(np.float32)
+        F_ref, gids_ref, eps_ref, labels_ref = reference(F_ref, gids_ref,
+                                                         W, b)
+        state = sh._reorg(state, *sh._put_models(W, b))
+        for got, want in ((state.F, F_ref), (state.gids, gids_ref),
+                          (state.eps, eps_ref), (state.labels, labels_ref)):
+            got = np.asarray(got)
+            assert got.dtype == want.dtype and got.shape == want.shape
+            assert got.tobytes() == want.tobytes(), step
+        pad = gids_ref == PAD_GID
+        assert not np.array_equal(gids_ref[~pad], np.arange(n))  # moved rows
+        assert pad.sum() == sh.n_pad - n
+        assert pad[-(sh.n_pad - n):].all()   # padding sorts last
+
+    hlo = sh._reorg.lower(state, *sh._put_models(W, b)).as_text()
+    table = f"tensor<{n_local}x{d}xf32>"
+    selects = [l for l in hlo.splitlines()   # the result's type comes last
+               if "stablehlo.select" in l and l.rstrip().endswith(table)]
+    assert not selects, selects[:2]
+    print("REORGANIZE_MATCHES_NUMPY")
+"""
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_multiview_reorganize_matches_numpy(shards):
+    """The multi-view reorganize on a (shards, 1) row mesh with padding
+    rows, twice in a row: the moved table, gids, margins and labels equal
+    a numpy reference (a stable argsort of each shard's nearest-boundary
+    distance, applied with `np.take`) bit for bit, padding rows last; and
+    the lowered program moves the table's rows with the gather alone, no
+    select over a table-shaped array."""
+    out = _run_subprocess(_REORGANIZE_VS_NUMPY.replace("SHARDS", str(shards)),
+                          devices=shards)
+    assert "REORGANIZE_MATCHES_NUMPY" in out
+
+
 # ---------------------------------------------------------------------------
 # Elastic scaling: checkpoint on one mesh, restore on a smaller one
 # ---------------------------------------------------------------------------

@@ -11,6 +11,7 @@ imported: only one process at a time may load the TPU library, and the
 test workers import every test file.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -68,6 +69,18 @@ def _device_bytes(compiled) -> int:
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
 
 
+def _entry_ops_of_shape(text: str, shape: str) -> list:
+    """The instructions of the compiled program's ENTRY computation whose
+    result is an array of `shape` (e.g. `f32[721408,1024]`), parameters
+    left out."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    want = re.compile(r"^\s*(?:ROOT )?%\S+ = " + re.escape(shape)
+                      + r"\{[^}]*\} ([\w-]+)\(")
+    return [line.strip() for line in entry.splitlines()
+            if (m := want.match(line)) and m.group(1) != "parameter"]
+
+
 @pytest.mark.parametrize("name", sorted(SHAPES))
 def test_band_kernel_compiles_for_v5e(one_chip_mesh, name):
     """The band kernel alone, at the padded table size and tiling the
@@ -109,10 +122,16 @@ def test_multiview_steps_compile_for_v5e(one_chip_mesh, step):
     fn = dr._update if step == "update" else dr._reorg
     compiled = fn.lower(state, W, b).compile()
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+    text = compiled.as_text()
     if step == "update":
-        assert "tpu_custom_call" in compiled.as_text()
+        assert "tpu_custom_call" in text
         table_bytes = dr.n_pad * dr.d * 4
         assert compiled.memory_analysis().output_size_in_bytes < table_bytes
+    else:
+        # the table moves in one pass: the row gather is the one
+        # table-shaped instruction, with no fill select after it
+        table = _entry_ops_of_shape(text, f"f32[{dr.n_pad},{dr.d}]")
+        assert len(table) == 1 and "gather" in table[0], table
 
 
 def test_probe_step_reads_one_row_for_v5e(one_chip_mesh):
@@ -141,8 +160,9 @@ def test_four_chip_steps_compile_for_v5e_2x2(four_chip_mesh, step):
     x 4,096 f32, k = 16) on four described chips: 180,352 rows a chip.
     The update step launches the kernel on each shard and ends in one
     all-reduce, of the 16 band widths and the 4 shards' streamed rows;
-    the reorganize crosses no chip. With reorganize's copy a chip holds
-    under 6 GB of the table, well inside its memory."""
+    the reorganize crosses no chip and writes a shard's rows once, by its
+    row gather. With reorganize's copy a chip holds under 6 GB of the
+    table, well inside its memory."""
     dr = ShardedMultiViewHazy(mesh=four_chip_mesh, n=721_000, d=4096, k=16,
                               M=1.0)
     assert dr.shards == 4 and dr.n_pad == 4 * 180_352
@@ -164,3 +184,5 @@ def test_four_chip_steps_compile_for_v5e_2x2(four_chip_mesh, step):
         assert len(crossing) == 1 and "s32[20]" in crossing[0], crossing
     else:
         assert not crossing, crossing[:2]
+        table = _entry_ops_of_shape(text, f"f32[180352,{dr.d}]")
+        assert len(table) == 1 and "gather" in table[0], table
