@@ -32,7 +32,6 @@ MODULES = [
     "benchmarks.sql_serve",         # relational front-end overhead vs direct
     "benchmarks.serve_concurrent",  # concurrent wire-protocol serving swarm
     "benchmarks.fleet_lag",         # freshness scheduler: TARGET_LAG fleet
-    "benchmarks.kernel_bench",      # framework kernels
 ]
 
 

@@ -3,9 +3,8 @@
 Creates a base table from a corpus, registers a hybrid multiclass
 classification view on it, streams DML (training inserts through the
 group-commit WAL), then reads it back with SELECTs and inspects the
-§3.4/§3.5 cost model with EXPLAIN. This replaces the ad-hoc driver
-pattern of `examples/serve_view.py` for the view workload — every
-interaction below is a SQL statement.
+§3.4/§3.5 cost model with EXPLAIN. Every interaction below is a SQL
+statement.
 
 Run:  PYTHONPATH=src python examples/sql_quickstart.py
 """

@@ -181,7 +181,7 @@ def check_purity(modules: ModuleSet) -> List[Finding]:
         # PUR004: shells mutating EngineState fields on non-self objects.
         # Applies to core/rdbms/storage modules (engine.py excepted) and
         # to out-of-tree files (the fixture corpus simulates shells);
-        # models/launch/data are not EngineState shells.
+        # the package's other directories are not EngineState shells.
         if _is_engine(path):
             continue
         try:

@@ -1,57 +1,39 @@
-"""Pod-scale sharded HAZY view maintenance (jit/shard_map twin of hazy.py).
+"""Row-sharded HAZY view maintenance on the device (jit/shard_map twin of
+multiview.py).
 
-Stateful-shell #3 over the functional core in `core/engine.py`: every
-algorithm rule — the Lemma 3.1 partition (`band_partition` inside the
-single-view step, `covering_windows`/`band_mask` inside the multi-view
-step, `probe_partition` inside the hybrid probe), the Eq. 2 waters update
-(host-side in the drivers, via `waters_update`) and the SKIING charge rule
-(via `Skiing`) — is imported from engine.py; this module owns sharding
-layout, shard_map plumbing and the kernel launch.
+Stateful shell #3 over the functional core in `core/engine.py`: every
+algorithm rule — the Lemma 3.1 partition (`covering_windows`/`band_mask`
+inside the update step, `probe_partition` inside the hybrid probe), the
+Eq. 2 waters update (host-side in the driver, via `waters_update`) and the
+SKIING charge rule (via `Skiing`) — is imported from engine.py; this
+module owns the sharding layout, the shard_map plumbing and the kernel
+launch.
 
-Single-view layout (DESIGN.md §2): entity rows sharded over ("pod","data"),
-feature dim over ("model",). All three maintenance steps need *zero
-cross-shard data movement* except a psum of per-shard eps partials over the
-model axis and scalar metric reductions:
-
-  * naive_update_step  — full eps recompute + relabel (the paper's naive
-                         eager baseline; memory-bound roofline anchor)
-  * hazy_update_step   — banded reclassify with a static capacity window
-                         (the paper's incremental step; bytes ∝ band)
-  * reorganize_step    — per-shard argsort + row gather (paper's re-sort;
-                         embarrassingly parallel — see DESIGN.md on why
-                         shard-local clustering preserves correctness)
-
-Multi-view layout: k one-vs-all views share ONE scratch table whose rows
-are kept in a shard-local SHARED clustering order (sorted by
-min_v |eps_v|, the distance to the nearest view's decision boundary, so
-every view's band is clustered near the front of the shard). The order is
-maintained entirely device-side: the reorganize step re-sorts it, the
-update step computes per-view covering windows of the Lemma 3.1 band in
-that order (`engine.covering_windows`) and relabels the union of the k
-windows with ONE `multiview_band_reclassify` Pallas launch, which streams
-each tile of that union once for all k views — no vmapped per-view dynamic
-slices, and no capacity: a window may span the whole shard, so SKIING
-alone decides when to reorganize. The kernel computes sign(w_v·f − b_v)
-from whole feature rows, so the scratch table is row-sharded and
-model-REPLICATED (the (k, d) models are tiny; the big model-sharded
-training jobs live in models/steps.py). The §3.5.2 hybrid read rides
-the same state: `make_multiview_hybrid_probe_step` looks the entity up in
-the eps-map, resolves what the waters can, and labels the views they
-cannot from the entity's one feature row, all in one program.
-
-Static band capacity (single view only): jit needs static shapes, so
-`hazy_update_step` processes the band through a `cap`-row window per shard
-(cap ≈ n_shard * cap_frac) and the host driver reorganizes when the band
-outgrows it. The multi-view kernel walks all of a shard's tiles with its
-index maps clamped into the union window, so its shapes are static without
-a capacity.
+Layout: k one-vs-all views (k = 1 is one case) share ONE scratch table,
+its rows sharded over ("pod", "data") and replicated over "model", and
+kept in a shard-local SHARED clustering order (sorted by min_v |eps_v|,
+the distance to the nearest view's decision boundary, so every view's band
+is clustered near the front of the shard). The order is maintained
+entirely device-side: the reorganize step re-sorts it, with no collective
+(the clustering is shard-local); the update step computes per-view
+covering windows of the Lemma 3.1 band in that order
+(`engine.covering_windows`) and relabels the union of the k windows with
+ONE `multiview_band_reclassify` Pallas launch, which streams each tile of
+that union once for all k views — no vmapped per-view dynamic slices, and
+no capacity: a window may span the whole shard, so SKIING alone decides
+when to reorganize. The kernel computes sign(w_v·f − b_v) from whole
+feature rows, hence the model-replicated rows (the (k, d) models are
+tiny). The §3.5.2 hybrid read rides the same state:
+`make_multiview_hybrid_probe_step` looks the entity up in the eps-map,
+resolves what the waters can, and labels the views they cannot from the
+entity's one feature row, all in one program.
 
 Observation: every jitted program is named for what it does (`PROGRAMS`),
 so the profiler's "XLA Modules" line and JAX's compile events say which
 one ran or recompiled. A driver counts the compiles of those programs in
 its metrics registry and mirrors every `repro.obs` span into the
-profiler's host trace (`observe`). The multi-view driver opens `round.*`
-spans inside a maintenance round and `read.*` spans inside a point read.
+profiler's host trace (`observe`). The driver opens `round.*` spans inside
+a maintenance round and `read.*` spans inside a point read.
 """
 from __future__ import annotations
 
@@ -64,8 +46,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.engine import (band_partition, classify, covering_windows,
-                               probe_partition, waters_update)
+from repro.core.engine import (classify, covering_windows, probe_partition,
+                               waters_update)
 from repro.kernels.band_reclassify.ops import multiview_band_reclassify
 from repro.obs import trace
 
@@ -73,39 +55,6 @@ from repro.obs import trace
 # pass, which would make the stored eps (and so the Lemma 3.1 band) only
 # bf16-accurate
 HIGHEST = jax.lax.Precision.HIGHEST
-
-
-class ShardedHazyState(NamedTuple):
-    F: jax.Array            # (n, d) bf16 — rows in shard-local eps-sorted order
-    eps: jax.Array          # (n,) f32  — stored-model eps (the eps-map)
-    labels: jax.Array       # (n,) int8
-    perm: jax.Array         # (n,) int32 — shard-local positions -> entity ids
-    w_stored: jax.Array     # (d,) f32
-    b_stored: jax.Array     # () f32
-    lw: jax.Array           # () f32
-    hw: jax.Array           # () f32
-
-
-def state_specs(n: int, d: int, mesh: Mesh, dtype=jnp.bfloat16):
-    """Abstract ShardedHazyState with shardings (dry-run inputs)."""
-    row_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    rows = P(row_axes)
-    rows_feat = P(row_axes, "model" if "model" in mesh.axis_names else None)
-    feat = P("model" if "model" in mesh.axis_names else None)
-
-    def sds(shape, dt, spec):
-        return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, spec))
-
-    return ShardedHazyState(
-        F=sds((n, d), dtype, rows_feat),
-        eps=sds((n,), jnp.float32, rows),
-        labels=sds((n,), jnp.int8, rows),
-        perm=sds((n,), jnp.int32, rows),
-        w_stored=sds((d,), jnp.float32, feat),
-        b_stored=sds((), jnp.float32, P()),
-        lw=sds((), jnp.float32, P()),
-        hw=sds((), jnp.float32, P()),
-    )
 
 
 def _row_axes(mesh: Mesh):
@@ -117,20 +66,13 @@ def row_shards(mesh: Mesh) -> int:
     return int(np.prod([mesh.shape[a] for a in _row_axes(mesh)]))
 
 
-def _specs(mesh: Mesh):
-    rows = _row_axes(mesh)
-    model = "model" if "model" in mesh.axis_names else None
-    return P(rows, model), P(rows), P(model)
-
-
 # ---------------------------------------------------------------------------
 # Observation: named programs, their compiles, and the span mirror
 # ---------------------------------------------------------------------------
 
-# the drivers' jitted programs, by the name each step function carries: the
+# the driver's jitted programs, by the name each step function carries: the
 # trace's "XLA Modules" line shows `jit_<name>`, compile events `jit(<name>)`
-PROGRAMS = ("band_update", "reorganize", "probe", "all_members",
-            "naive_update")
+PROGRAMS = ("band_update", "reorganize", "probe", "all_members")
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _watching: "weakref.WeakSet" = weakref.WeakSet()   # registries counting
 _listening = False                                  # compiles
@@ -172,201 +114,8 @@ def observe(metrics: Optional[Any] = None) -> None:
 
 
 # ---------------------------------------------------------------------------
-# Steps (built per mesh; call under `with mesh:` or pass to jit/lower)
-# ---------------------------------------------------------------------------
-
-def make_naive_update_step(mesh: Mesh):
-    pf, pr, pw = _specs(mesh)
-    model_ax = "model" if "model" in mesh.axis_names else None
-
-    def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
-        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w,
-                       precision=HIGHEST)
-        if model_ax:
-            z = jax.lax.psum(z, model_ax)
-        z = z - b
-        return classify(z, xp=jnp)
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
-        out_specs=pr)
-
-    def naive_update(state: ShardedHazyState, w, b):
-        labels = fn(*state, w, b)
-        return state._replace(labels=labels)
-
-    return naive_update
-
-
-def make_hazy_update_step(mesh: Mesh, n: int, cap_frac: float = 1 / 64):
-    """Banded incremental step. Returns (state', width_total)."""
-    pf, pr, pw = _specs(mesh)
-    model_ax = "model" if "model" in mesh.axis_names else None
-    rows = _row_axes(mesh)
-    n_local = n // row_shards(mesh)
-    cap = max(64, int(n_local * cap_frac))
-
-    def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
-        # Hölder waters were updated on the host (scalars); locate the band
-        # [lw, hw) via THE shared Lemma 3.1 partition (engine.band_partition
-        # — the same helper the host engines and the hybrid probe use).
-        lo, hi = band_partition(eps, lw, hw, xp=jnp)
-        lo, hi = lo.astype(jnp.int32), hi.astype(jnp.int32)
-        width = hi - lo
-        start = jnp.clip(lo, 0, jnp.maximum(0, eps.shape[0] - cap))
-        Fb = jax.lax.dynamic_slice(F, (start, 0), (cap, F.shape[1]))
-        z = jnp.einsum("nd,d->n", Fb.astype(jnp.float32), w,
-                       precision=HIGHEST)
-        if model_ax:
-            z = jax.lax.psum(z, model_ax)
-        z = z - b
-        new = classify(z, xp=jnp)
-        old = jax.lax.dynamic_slice(labels, (start,), (cap,))
-        idx = jnp.arange(cap) + start
-        in_band = (idx >= lo) & (idx < hi)
-        merged = jnp.where(in_band, new, old)
-        labels = jax.lax.dynamic_update_slice(labels, merged, (start,))
-        wsum, wmax = width, width
-        for ax in rows:
-            wsum = jax.lax.psum(wsum, ax)
-            wmax = jax.lax.pmax(wmax, ax)
-        return labels, wsum, wmax
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
-        out_specs=(pr, P(), P()))
-
-    def band_update(state: ShardedHazyState, w, b):
-        labels, wsum, wmax = fn(*state, w, b)
-        return state._replace(labels=labels), wsum, wmax
-
-    return band_update, cap
-
-
-def make_reorganize_step(mesh: Mesh):
-    """Per-shard sort by fresh eps + row gather; resets the stored model.
-
-    No collectives beyond the model-axis psum of eps partials: the
-    clustering is shard-local by design (DESIGN.md §2)."""
-    pf, pr, pw = _specs(mesh)
-    model_ax = "model" if "model" in mesh.axis_names else None
-
-    def local(F, eps, labels, perm, w_s, b_s, lw, hw, w, b):
-        z = jnp.einsum("nd,d->n", F.astype(jnp.float32), w,
-                       precision=HIGHEST)
-        if model_ax:
-            z = jax.lax.psum(z, model_ax)
-        z = z - b
-        order = jnp.argsort(z)
-        eps_new = z[order]
-        F_new = jnp.take(F, order, axis=0)
-        perm_new = jnp.take(perm, order)
-        labels_new = classify(eps_new, xp=jnp)
-        return F_new, eps_new, labels_new, perm_new
-
-    fn = jax.shard_map(
-        local, mesh=mesh,
-        in_specs=(pf, pr, pr, pr, pw, P(), P(), P(), pw, P()),
-        out_specs=(pf, pr, pr, pr))
-
-    def reorganize(state: ShardedHazyState, w, b):
-        F, eps, labels, perm = fn(*state, w, b)
-        return ShardedHazyState(F, eps, labels, perm, w, b,
-                                jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-
-    return reorganize
-
-
-def make_all_members_step(mesh: Mesh):
-    pf, pr, pw = _specs(mesh)
-    rows = _row_axes(mesh)
-
-    def local(labels):
-        c = jnp.sum((labels == 1).astype(jnp.int32))
-        for ax in rows:
-            c = jax.lax.psum(c, ax)
-        return c
-
-    fn = jax.shard_map(local, mesh=mesh, in_specs=(pr,), out_specs=P())
-
-    def all_members(state):
-        return fn(state.labels)
-
-    return all_members
-
-
-# ---------------------------------------------------------------------------
-# Host-side driver (real runs; the Waters/Skiing control loop stays host-side
-# exactly as the paper's strategy is driven outside the storage engine)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class ShardedHazy:
-    mesh: Mesh
-    n: int
-    d: int
-    M: float
-    p: float = 2.0
-    alpha: float = 1.0
-    cap_frac: float = 1 / 64
-
-    def __post_init__(self):
-        self._naive = jax.jit(make_naive_update_step(self.mesh))
-        hz, self.cap = make_hazy_update_step(self.mesh, self.n, self.cap_frac)
-        self._hazy = jax.jit(hz)
-        self._reorg = jax.jit(make_reorganize_step(self.mesh))
-        self._count = jax.jit(make_all_members_step(self.mesh))
-        from repro.core.skiing import Skiing
-        self.skiing = Skiing(S=1.0, alpha=self.alpha)
-        self.lw = 0.0
-        self.hw = 0.0
-
-    def init_state(self, F: np.ndarray) -> ShardedHazyState:
-        specs = state_specs(self.n, self.d, self.mesh, dtype=jnp.bfloat16)
-        put = lambda x, s: jax.device_put(x, s.sharding)
-        state = ShardedHazyState(
-            F=put(F.astype(np.float32), specs.F),
-            eps=put(np.zeros(self.n, np.float32), specs.eps),
-            labels=put(np.ones(self.n, np.int8), specs.labels),
-            perm=put(np.arange(self.n, dtype=np.int32), specs.perm),
-            w_stored=put(np.zeros(self.d, np.float32), specs.w_stored),
-            b_stored=put(np.zeros((), np.float32), specs.b_stored),
-            lw=put(np.zeros((), np.float32), specs.lw),
-            hw=put(np.zeros((), np.float32), specs.hw),
-        )
-        return self._reorg(state, jnp.zeros(self.d, jnp.float32), jnp.zeros((), jnp.float32))
-
-    def apply_model(self, state: ShardedHazyState, w, b) -> ShardedHazyState:
-        """One eager round under SKIING (modeled costs: bytes ∝ rows touched)."""
-        if self.skiing.should_reorganize():
-            state = self._reorg(state, w, b)
-            self.skiing.record_reorg()
-            self.lw = self.hw = 0.0
-            return state
-        lw, hw = waters_update(self.lw, self.hw, np.asarray(w), float(b),
-                               np.asarray(state.w_stored),
-                               float(state.b_stored), self.M, self.p)
-        self.lw, self.hw = float(lw), float(hw)
-        state, wsum, wmax = self._hazy(
-            state._replace(lw=jnp.float32(self.lw), hw=jnp.float32(self.hw)), w, b)
-        if int(wmax) > self.cap:
-            # capacity window overflowed on some shard: fall back to reorg
-            # (correctness preserved; SKIING would reorganize soon anyway)
-            state = self._reorg(state, w, b)
-            self.skiing.record_reorg()
-            self.lw = self.hw = 0.0
-            return state
-        self.skiing.record_incremental(int(wsum) / self.n)  # modeled cost
-        return state
-
-    def all_members(self, state) -> int:
-        return int(self._count(state))
-
-
-# ---------------------------------------------------------------------------
-# Multi-view twin: k one-vs-all views over ONE shared scratch table kept in
+# Steps (built per mesh; call under `with mesh:` or pass to jit/lower) and
+# the host driver: k one-vs-all views over ONE shared scratch table kept in
 # a device-resident SHARED clustering order (sorted by min_v |eps_v|). The
 # update step relabels the k covering windows with ONE Pallas kernel launch.
 # ---------------------------------------------------------------------------

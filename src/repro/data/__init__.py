@@ -1,4 +1,3 @@
-from repro.data.lm import TokenStream
 from repro.data.corpora import (forest_like, dblife_like, citeseer_like,
                                 cora_like, multiclass_corpus,
                                 multiclass_example_stream, MulticlassCorpus,

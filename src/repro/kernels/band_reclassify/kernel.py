@@ -1,13 +1,12 @@
-"""band_reclassify Pallas kernels — the paper's incremental step as a kernel.
+"""band_reclassify Pallas kernel — the paper's incremental step as a kernel.
 
 Only tiles of the band's window are streamed HBM→VMEM (tile-granular
-version of "read only the B+-tree range"). The single-view kernel covers a
-fixed `cap`-row window shifted by the scalar-prefetch `start_block`; the
-multi-view kernel walks the shard's tiles with its index maps clamped into
-the union of the k views' windows, so each tile there is read once for all
-k views and nothing outside it is read. Labels are updated in place via
-input/output aliasing — rows of a streamed tile outside a view's window
-are preserved with a predicated merge.
+version of "read only the B+-tree range"). The kernel walks the shard's
+tiles with its index maps clamped into the union of the k views' windows,
+so each tile there is read once for all k views and nothing outside it is
+read. Labels are updated in place via input/output aliasing — rows of a
+streamed tile outside a view's window are preserved with a predicated
+merge.
 """
 from __future__ import annotations
 
@@ -17,20 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _band_kernel(scalars_ref,           # (2,) i32: [start_block, width]
-                 w_ref, b_ref, f_ref, lab_in_ref, lab_out_ref):
-    i = pl.program_id(0)
-    width = scalars_ref[1]
-    bn = f_ref.shape[0]
-    f = f_ref[...].astype(jnp.float32)
-    w = w_ref[...].astype(jnp.float32)
-    eps = jnp.sum(f * w, axis=1, keepdims=True) - b_ref[0, 0]
-    new = jnp.where(eps >= 0, 1, -1).astype(jnp.int8)
-    offs = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
-    in_band = offs < width
-    lab_out_ref[...] = jnp.where(in_band, new, lab_in_ref[...])
 
 
 def _mv_band_kernel(tiles_ref,          # (2,) i32 SMEM: union window tiles
@@ -108,37 +93,3 @@ def multiview_band_reclassify(F, labels, W, b, tiles, starts, ends, *,
         interpret=interpret,
     )(tiles.astype(jnp.int32), W, col(b, jnp.float32),
       col(starts, jnp.int32), col(ends, jnp.int32), F, labels)
-
-
-@functools.partial(jax.jit, static_argnames=("cap", "block_n", "interpret"))
-def band_reclassify(F_sorted, labels, w, b, start_block, width, *,
-                    cap: int = 4096, block_n: int = 512,
-                    interpret: bool = False):
-    """F_sorted: (n, d); labels: (n, 1) int8 (updated in place);
-    start_block: () i32 — band start in units of block_n rows;
-    width: () i32 — band rows counted from the window start.
-
-    Returns updated labels. HBM reads: cap rows of F + cap labels only."""
-    n, d = F_sorted.shape
-    assert cap % block_n == 0 and n % block_n == 0
-    grid = (cap // block_n,)
-    scalars = jnp.stack([start_block.astype(jnp.int32), width.astype(jnp.int32)])
-
-    out = pl.pallas_call(
-        _band_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, d), lambda i, s: (0, 0)),
-                pl.BlockSpec((1, 1), lambda i, s: (0, 0)),
-                pl.BlockSpec((block_n, d), lambda i, s: (s[0] + i, 0)),
-                pl.BlockSpec((block_n, 1), lambda i, s: (s[0] + i, 0)),
-            ],
-            out_specs=pl.BlockSpec((block_n, 1), lambda i, s: (s[0] + i, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.int8),
-        input_output_aliases={4: 0},
-        interpret=interpret,
-    )(scalars, w[None, :], b.reshape(1, 1), F_sorted, labels)
-    return out

@@ -4,10 +4,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from repro.kernels.band_reclassify.kernel import (
-    band_reclassify as _kernel,
     multiview_band_reclassify as _mv_kernel,
 )
-from repro.kernels.band_reclassify.ref import band_reclassify_ref  # noqa: F401
 
 
 def multiview_band_reclassify(F, labels, W, b, start_rows, end_rows, *,
@@ -34,21 +32,3 @@ def multiview_band_reclassify(F, labels, W, b, start_rows, end_rows, *,
                      interpret=interpret)
     return out, (tiles[1] - tiles[0]) * block_n
 
-
-def band_reclassify(F_sorted, labels, w, b, start_row, end_row, *,
-                    cap: int = 4096, block_n: int = 512,
-                    interpret: bool = False):
-    """Relabel rows [start_row, end_row) of the eps-sorted table under (w,b).
-
-    labels: (n,) int8. The window is tile-aligned and capacity-clamped; the
-    caller (SKIING driver) must ensure end_row − aligned_start ≤ cap."""
-    n, d = F_sorted.shape
-    start_row = jnp.asarray(start_row, jnp.int32)
-    end_row = jnp.asarray(end_row, jnp.int32)
-    start_block = jnp.clip(start_row // block_n, 0,
-                           max(0, (n - cap) // block_n))
-    width = jnp.clip(end_row - start_block * block_n, 0, cap)
-    out = _kernel(F_sorted, labels[:, None], w, jnp.asarray(b, jnp.float32),
-                  start_block, width, cap=cap, block_n=block_n,
-                  interpret=interpret)
-    return out[:, 0]

@@ -1,4 +1,4 @@
-"""Pure-jnp oracles for the band_reclassify kernels."""
+"""Pure-jnp oracle for the band_reclassify kernel."""
 import jax
 import jax.numpy as jnp
 
@@ -15,15 +15,3 @@ def multiview_band_reclassify_ref(F, labels, W, b, start_rows, end_rows):
     inside = (rows >= start_rows[:, None]) & (rows < end_rows[:, None])
     return jnp.where(inside, new, labels)
 
-
-def band_reclassify_ref(F_sorted, labels, w, b, start_block, width, *,
-                        cap: int, block_n: int):
-    n, d = F_sorted.shape
-    start = start_block * block_n
-    Fb = jax.lax.dynamic_slice(F_sorted, (start, 0), (cap, d))
-    eps = jnp.einsum("nd,d->n", Fb.astype(jnp.float32), w.astype(jnp.float32)) - b
-    new = jnp.where(eps >= 0, 1, -1).astype(jnp.int8)[:, None]
-    old = jax.lax.dynamic_slice(labels, (start, 0), (cap, 1))
-    in_band = (jnp.arange(cap) < width)[:, None]
-    merged = jnp.where(in_band, new, old)
-    return jax.lax.dynamic_update_slice(labels, merged, (start, 0))

@@ -6,7 +6,7 @@ traffic is exactly one read of r/k/v/decay and one write of the output per
 token (the recurrence state never round-trips to HBM, which is what makes
 the attention-free family memory-optimal on TPU).
 
-Math (identical to models/rwkv6.wkv_chunked, the deployed training path):
+Math (checked against ref.py's sequential recurrence):
     a       = cumsum(log-decay) within the chunk           (<= 0)
     o_inter = (r ⊙ exp(a_prev)) · S_in
     o_intra = tril_strict[(r ⊙ exp(a_prev))(k ⊙ exp(-a))ᵀ] · v   (clipped exp)

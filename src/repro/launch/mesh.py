@@ -1,5 +1,5 @@
-"""Production meshes. Import never touches jax device state — the mesh is
-built inside the function, per the dry-run contract."""
+"""Device meshes. Import never touches jax device state — each mesh is
+built inside its function."""
 from __future__ import annotations
 
 import jax
@@ -10,25 +10,6 @@ def make_mesh(shape, axes):
     """`jax.make_mesh` with every axis Auto (sharding left to the compiler
     and to the shard_map specs)."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
-
-
-def make_elastic_mesh(num_devices: int, *, model_parallel: int = 16):
-    """Rebuild a mesh from the devices that survive a failure. Keeps the
-    model axis (TP degree is a property of the checkpointed layout) and
-    shrinks the data axis; restore_checkpoint reshards onto it."""
-    devices = jax.devices()[:num_devices]
-    assert num_devices % model_parallel == 0, (num_devices, model_parallel)
-    data = num_devices // model_parallel
-    import numpy as np
-    arr = np.array(devices).reshape(data, model_parallel)
-    from jax.sharding import Mesh
-    return Mesh(arr, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def make_row_mesh(shards: int, axes=("data", "model")):

@@ -20,9 +20,9 @@ surface for our engines:
   * `wire`/`server`/`client` — length-prefixed-JSON protocol, the asyncio
                  SQL server (N concurrent sessions over ONE executor),
                  and the blocking client
-                 (`python -m repro.launch.serve --mode sql --serve ...`)
+                 (`python -m repro.launch.serve --serve ...`)
   * `repl`     — interactive / scripted entry point
-                 (`python -m repro.launch.serve --mode sql`)
+                 (`python -m repro.launch.serve`)
 """
 from repro.rdbms.ast_nodes import (AlterView, Commit, CreateTable,
                                    CreateView, Delete, ExecutePrepared,

@@ -1,6 +1,6 @@
 """SQL entry point: interactive REPL and scripted execution.
 
-Used by `python -m repro.launch.serve --mode sql` (interactive), with
+Used by `python -m repro.launch.serve` (interactive), with
 `--script f.sql` (run a file) or `--execute "stmt; stmt"` (one-shot).
 """
 from __future__ import annotations

@@ -45,12 +45,12 @@ N, D, K = 256, 16, 3
 
 def test_single_source_band_partition():
     """One Lemma 3.1 partition: hazy/multiview/sharded all bind the very
-    function objects from engine.py (both the sorted-row and point-probe
-    forms), and none of them re-derives the partition with a raw
+    function objects from engine.py (the sorted-row form on the host, the
+    shared-order covering windows on the device, the point-probe form in
+    all three), and none of them re-derives the partition with a raw
     searchsorted against the waters."""
     assert hazy_mod.band_partition is E.band_partition
     assert mv_mod.band_partition is E.band_partition
-    assert sh_mod.band_partition is E.band_partition
     assert hazy_mod.probe_partition is E.probe_partition
     assert mv_mod.probe_partition is E.probe_partition
     assert sh_mod.probe_partition is E.probe_partition
